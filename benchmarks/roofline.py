@@ -50,10 +50,13 @@ KERNELS_OUT = os.path.join(os.path.dirname(__file__), "..",
 # 32.1e6 to 3.6e6 bytes — the kernel streams operand blocks and keeps
 # the [S, win, K] intermediates in VMEM.  monitor_fused adds the
 # in-kernel worst-bin/classify reduction + blocked escalation scan on
-# top and still never materializes per-sample amplitudes.
+# top and still never materializes per-sample amplitudes.  Both count
+# the in-kernel log-step lane scan (Mosaic has no cumsum): ceil(log2(win))
+# masked adds per sample and bin in place of one, 4.4x the FLOPs of the
+# cumsum form at the reference shape, bytes unchanged.
 KERNEL_BUDGETS = {
-    "sliding_goertzel": {"max_flops": 13.0e6, "max_bytes": 4.3e6},
-    "monitor_fused": {"max_flops": 24.0e6, "max_bytes": 21.9e6},
+    "sliding_goertzel": {"max_flops": 69.0e6, "max_bytes": 4.3e6},
+    "monitor_fused": {"max_flops": 80.0e6, "max_bytes": 21.9e6},
     "goertzel_fingerprint": {"max_flops": 0.73e6, "max_bytes": 1.8e6},
     "warmstart_mlp": {"max_flops": 0.78e6, "max_bytes": 0.28e6},
     "ballast": {"max_flops": 10.4e9, "max_bytes": 103.2e6},
@@ -68,20 +71,21 @@ KERNEL_BUDGETS = {
 # by the Tier-3 kernel checks); it stays FLOPs/bytes-only here.
 KERNEL_PRIMITIVES = {
     "sliding_goertzel": ("kernels.sliding_bin_power", {
-        "add": 20, "broadcast_in_dim": 6, "concatenate": 10, "cond": 1,
-        "convert_element_type": 2, "cumsum": 8, "device_put": 3, "div": 2,
-        "eq": 1, "get": 30, "iota": 2, "min": 1, "mul": 42, "neg": 4,
-        "pallas_call": 1, "pjit": 9, "program_id": 1, "reduce_sum": 1,
-        "reshape": 2, "slice": 25, "sqrt": 4, "sub": 13, "swap": 16}),
+        "add": 109, "broadcast_in_dim": 126, "concatenate": 10, "cond": 1,
+        "convert_element_type": 123, "div": 2, "eq": 1, "ge": 88, "get": 30,
+        "iota": 10, "jit": 122, "min": 1, "mul": 42, "ne": 32, "neg": 4,
+        "pallas_call": 1, "program_id": 1, "reduce_sum": 1, "reshape": 2,
+        "roll": 88, "select_n": 120, "slice": 27, "sqrt": 4, "squeeze": 2,
+        "sub": 14, "swap": 16}),
     "monitor_fused": ("kernels.monitor_fused", {
-        "add": 35, "and": 17, "broadcast_in_dim": 16, "concatenate": 11,
-        "cond": 2, "convert_element_type": 21, "cumsum": 8, "device_put": 3,
-        "div": 4, "eq": 8, "ge": 6, "get": 33, "gt": 7, "iota": 5, "le": 2,
-        "lt": 5, "max": 5, "min": 5, "mul": 46, "ne": 5, "neg": 4, "not": 4,
-        "pallas_call": 1, "program_id": 1, "pjit": 39, "reduce_and": 2,
-        "reduce_max": 6, "reduce_sum": 2, "rem": 2, "reshape": 6, "scan": 2,
-        "select_n": 27, "sign": 4, "slice": 30, "sqrt": 4, "squeeze": 2,
-        "sub": 28, "swap": 19}),
+        "add": 125, "and": 17, "broadcast_in_dim": 136, "concatenate": 11,
+        "cond": 2, "convert_element_type": 142, "div": 4, "eq": 8, "ge": 94,
+        "get": 33, "gt": 7, "iota": 14, "jit": 152, "le": 2, "lt": 5,
+        "max": 5, "min": 5, "mul": 46, "ne": 37, "neg": 4, "not": 4,
+        "pallas_call": 1, "program_id": 1, "reduce_and": 2, "reduce_max": 6,
+        "reduce_sum": 2, "rem": 2, "reshape": 6, "roll": 88, "scan": 2,
+        "select_n": 147, "sign": 4, "slice": 32, "sqrt": 4, "squeeze": 4,
+        "sub": 29, "swap": 19}),
     "goertzel_fingerprint": ("serve.fingerprint", {
         "add": 1, "div": 2, "dot_general": 2, "mul": 3, "reduce_sum": 1,
         "sqrt": 1, "sub": 1}),

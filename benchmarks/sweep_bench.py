@@ -418,9 +418,15 @@ def run_scale(n_target: int, chunk: int) -> None:
 def run_resume_smoke(n_target: int = 500, chunk: int = 100) -> None:
     """SIGKILL a resumable streamed run at a chunk boundary in a worker
     subprocess, resume it in a second worker, and require the resumed
-    records to be bit-identical to an uninterrupted in-process run."""
+    records to be bit-identical to an uninterrupted in-process run.  The
+    parent and both workers are pinned to the CPU: the check compares
+    like with like, and no worker waits for a chip the parent holds."""
     import glob
 
+    from repro.parallel import distributed as D
+
+    D.pin_cpu()
+    cpu_env = dict(os.environ, JAX_PLATFORMS="cpu")
     study = _scale_study(n_target)
     ref = study.run(stream=chunk).to_records()
 
@@ -429,7 +435,8 @@ def run_resume_smoke(n_target: int = 500, chunk: int = 100) -> None:
     die_after = 2
     kill = subprocess.run(_resume_cmd(n_target, chunk, ck,
                                       die_after=die_after),
-                          stdout=subprocess.PIPE, text=True, timeout=600)
+                          stdout=subprocess.PIPE, text=True, timeout=600,
+                          env=cpu_env)
     assert kill.returncode == -signal.SIGKILL, \
         f"worker survived its own SIGKILL: rc={kill.returncode}"
     survivors = glob.glob(os.path.join(ck, "chunks", "*", "chunk_*"))
@@ -437,7 +444,7 @@ def run_resume_smoke(n_target: int = 500, chunk: int = 100) -> None:
         f"kill before checkpoints were written: {survivors}"
 
     res = _worker_json(_resume_cmd(n_target, chunk, ck, out_path=out_path),
-                       timeout=600)
+                       timeout=600, env=cpu_env)
     with open(out_path) as fh:
         got = json.load(fh)
     assert got == ref, \

@@ -129,7 +129,7 @@ def _build_simulate_step():
                  jnp.full((B,), 256.0, jnp.float32), si["gpus"], si["bats"],
                  on, on, si["keys"], None, limits),
             dict(cfg=si["cfg"], hw=si["hw"], spec=spec.family(),
-                 spectra=False))
+                 spectra=False, plan=None))
 
 
 def _build_design_gradient_step():

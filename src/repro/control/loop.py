@@ -192,8 +192,7 @@ def watch_trace(w: np.ndarray, dt: float, *, spec, n_chips: int,
     # the reference the detection lead is measured against when the
     # controller successfully prevents the observed breach
     raw_amps = np.asarray(sliding_bin_power(
-        source.raw, float(dt), tuple(detector.freqs), win=detector.win,
-        interpret=True))
+        source.raw, float(dt), tuple(detector.freqs), win=detector.win))
     over = np.nonzero(raw_amps.max(axis=1) > cfg.breach_w)[0]
     if len(over):
         log.counterfactual_breach_t_s = float(over[0] * dt)

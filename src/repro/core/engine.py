@@ -267,6 +267,25 @@ def _simulate_one(levels, shifts, n_chips, dev, rack, dev_on, rack_on, key,
                          spectra)
 
 
+def _over_rows(rows, plan: Optional[ScenarioShardPlan], shared, row_args):
+    """``rows(*shared, *row_args)``, where ``rows`` vmaps over the leading
+    scenario axis of ``row_args``.  Under a plan of more than one shard
+    it runs inside a ``shard_map`` over the scenario axis, so each device
+    runs its own rows (``shared`` is replicated): the Pallas kernels of a
+    row (the backstop's monitor) cannot be partitioned automatically.
+    The body is row-local and has no collectives, so the varying-axis
+    check is off: it would only ask every scan's constant initial carry
+    to be cast to varying."""
+    if plan is None or plan.n_shards <= 1:
+        return rows(*shared, *row_args)
+    rows_spec = jax.sharding.PartitionSpec(plan.axis)
+    return jax.shard_map(
+        rows, mesh=plan.mesh,
+        in_specs=((jax.sharding.PartitionSpec(),) * len(shared)
+                  + (rows_spec,) * len(row_args)),
+        out_specs=rows_spec, check_vma=False)(*shared, *row_args)
+
+
 # ``levels`` (argnum 0) is the one O(B*n) host->device input of every
 # pipeline call; donating it lets XLA reuse its buffer for the same-shape
 # waveform outputs, so a streaming chunk holds one buffer fewer in flight.
@@ -274,15 +293,19 @@ def _simulate_one(levels, shifts, n_chips, dev, rack, dev_on, rack_on, key,
 # ``limits`` its traced thresholds, so same-family specs share the
 # executable (see UtilitySpec.family()).
 @functools.partial(jax.jit, donate_argnums=(0,),
-                   static_argnames=("cfg", "hw", "spec", "spectra"))
+                   static_argnames=("cfg", "hw", "spec", "spectra", "plan"))
 def _simulate_vmapped(levels, shifts, n_chips, dev, rack, dev_on, rack_on,
                       keys, n_valid, limits, *, cfg: WaveformConfig,
                       hw: Hardware, spec: Optional[UtilitySpec],
-                      spectra: bool):
-    return jax.vmap(
-        lambda L, S, N, D, R, Do, Ro, K, V: _simulate_one(
-            L, S, N, D, R, Do, Ro, K, V, limits, cfg, hw, spec, spectra)
-    )(levels, shifts, n_chips, dev, rack, dev_on, rack_on, keys, n_valid)
+                      spectra: bool, plan: Optional[ScenarioShardPlan]):
+    def rows(limits, *row_args):
+        return jax.vmap(
+            lambda L, S, N, D, R, Do, Ro, K, V: _simulate_one(
+                L, S, N, D, R, Do, Ro, K, V, limits, cfg, hw, spec, spectra)
+        )(*row_args)
+    return _over_rows(rows, plan, (limits,),
+                      (levels, shifts, n_chips, dev, rack, dev_on, rack_on,
+                       keys, n_valid))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,),
@@ -295,20 +318,24 @@ def _synth_vmapped(levels, shifts, n_chips, n_valid, *, cfg: WaveformConfig,
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "hw", "spec", "spectra",
-                                             "chip_outputs"))
+                                             "chip_outputs", "plan"))
 def _mitigate_vmapped(chip_u, dcraw_u, u_idx, shifts, n_chips, dev, rack,
                       dev_on, rack_on, keys, n_valid, limits, *,
                       cfg: WaveformConfig, hw: Hardware,
                       spec: Optional[UtilitySpec], spectra: bool,
-                      chip_outputs: bool):
+                      chip_outputs: bool, plan: Optional[ScenarioShardPlan]):
     """Per-scenario suffix over rows that *share* synthesized prefixes:
     ``chip_u``/``dcraw_u`` hold one entry per unique (workload, fleet,
     seed) and ``u_idx`` maps each scenario row to its prefix."""
-    return jax.vmap(
-        lambda U, S, N, D, R, Do, Ro, K, V: _mitigate_one(
-            chip_u[U], dcraw_u[U], S, N, D, R, Do, Ro, K, V, limits, cfg,
-            hw, spec, spectra, chip_outputs)
-    )(u_idx, shifts, n_chips, dev, rack, dev_on, rack_on, keys, n_valid)
+    def rows(chip_u, dcraw_u, limits, *row_args):
+        return jax.vmap(
+            lambda U, S, N, D, R, Do, Ro, K, V: _mitigate_one(
+                chip_u[U], dcraw_u[U], S, N, D, R, Do, Ro, K, V, limits,
+                cfg, hw, spec, spectra, chip_outputs)
+        )(*row_args)
+    return _over_rows(rows, plan, (chip_u, dcraw_u, limits),
+                      (u_idx, shifts, n_chips, dev, rack, dev_on, rack_on,
+                       keys, n_valid))
 
 
 # ---------------------------------------------------------------------------
@@ -540,14 +567,14 @@ def simulate_batch(
             row_args, out_B = shard.shard_batch(row_args, B)
         res = _mitigate_vmapped(chip_u, dcraw_u, *row_args, limits,
                                 cfg=cfg, hw=hw, spec=family, spectra=spectra,
-                                chip_outputs=chip_outputs)
+                                chip_outputs=chip_outputs, plan=shard)
     else:
         args = (jnp.asarray(np.stack(level_rows), jnp.float32), shifts,
                 chips_f, dev, rack, dev_on, rack_on, keys_arr, n_valid_arr)
         if shard is not None:
             args, out_B = shard.shard_batch(args, B)
         res = _simulate_vmapped(*args, limits, cfg=cfg, hw=hw, spec=family,
-                                spectra=spectra)
+                                spectra=spectra, plan=shard)
     if host_arrays:
         # single-process this is the plain np.asarray(+slice) host pull;
         # multi-process it is one replicate-all collective first

@@ -174,7 +174,10 @@ class UtilitySpec:
         # ---- ramps (averaged over the metering window)
         k = max(int(self.time.ramp_window_s / dt), 1)
         if w.shape[-1] > k:
-            box = jnp.convolve(w, jnp.ones(k, jnp.float32) / k, mode="valid")
+            # HIGHEST: a TPU convolution otherwise rounds its f32 operands
+            # to bf16, a few hundred watts at fleet scale
+            box = jnp.convolve(w, jnp.ones(k, jnp.float32) / k, mode="valid",
+                               precision=jax.lax.Precision.HIGHEST)
             dp = jnp.diff(box) / dt
             m["max_ramp_up_w_per_s"] = jnp.maximum(dp.max(), 0.0)
             m["max_ramp_down_w_per_s"] = jnp.maximum(-dp.min(), 0.0)

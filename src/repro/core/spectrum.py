@@ -9,6 +9,7 @@ from __future__ import annotations
 
 from typing import Dict, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -50,8 +51,10 @@ def goertzel_bin_amplitudes_jax(x: jnp.ndarray, dt: float,
     xac = x - x.mean()
     t = np.arange(n) * dt
     ph = np.exp(-2j * np.pi * np.asarray(freqs)[:, None] * t[None, :])
-    re = jnp.asarray(ph.real, jnp.float32) @ xac
-    im = jnp.asarray(ph.imag, jnp.float32) @ xac
+    # HIGHEST: a TPU matmul otherwise rounds its f32 operands to bf16
+    hi = jax.lax.Precision.HIGHEST
+    re = jnp.matmul(jnp.asarray(ph.real, jnp.float32), xac, precision=hi)
+    im = jnp.matmul(jnp.asarray(ph.imag, jnp.float32), xac, precision=hi)
     return jnp.sqrt(re * re + im * im) * 2.0 / n
 
 

@@ -255,7 +255,8 @@ class TelemetrySource:
         lag = int(round(self.latency_s / dt))
         if self.averaged and k > 1:
             kernel = jnp.ones(k, jnp.float32) / k
-            base = jnp.convolve(w, kernel, mode="full")[:n]
+            base = jnp.convolve(w, kernel, mode="full",
+                                precision=jax.lax.Precision.HIGHEST)[:n]
         else:
             base = w
         idx = np.clip((np.arange(n) // k) * k - lag, 0, n - 1)

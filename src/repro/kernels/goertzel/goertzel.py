@@ -176,43 +176,79 @@ def sliding_goertzel_pallas(xseg: jax.Array, cosp: jax.Array,
 # v2: lane-major layout, per-bin unrolled, optional in-kernel escalation
 # ---------------------------------------------------------------------------
 
-def _bin_amps_lane_major(x, c_ref, s_ref, r_ref, pre_re, pre_im, scale,
-                         *, win: int, k: int):
-    """Shared v2 kernel core: per-bin sliding amplitudes on [Bs, win]
-    lane-major rows.  Yields (bin index, warm-up-scaled amp block) and
-    updates the prefix-state scratch in place.  The K bins unroll as
-    separate [Bs, win] computations — the long window axis stays on
-    lanes, and the tables' padded sublane rows (k..KP-1) are never read.
+def _rounded(p):
+    """The product ``p`` itself (a zero's sign aside), behind a select.
+    XLA's CPU backend contracts a multiply and an add that land in one
+    fusion into an FMA, and whether they land in one fusion depends on
+    the program around them.  Every product that feeds an add goes
+    through here, so the interpret-mode kernel at any ``block_s``, the
+    online single-segment calls and the jnp mirror round alike and stay
+    bitwise equal.  On the chip it costs a compare and a select."""
+    return jnp.where(p != 0.0, p, 0.0)
+
+
+def _lane_cumsum(v, roll=pltpu.roll):
+    """Inclusive prefix sum along the lanes (axis 1) of a ``[rows, win]``
+    block: a log-step scan of ``ceil(log2(win))`` rolls and lane-masked
+    f32 adds.  Mosaic has no cumsum lowering, and a triangular matmul
+    would run its default bf16 MXU passes over MW-scale sums.  The mask
+    also hides whatever a roll over a lane-padded width rotates into
+    lanes ``< shift``.  ``roll`` is ``pltpu.roll`` inside a kernel and
+    ``jnp.roll`` (the same op graph in interpret mode) in the jnp
+    mirror."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+    v = _rounded(v)
+    shift = 1
+    while shift < v.shape[1]:
+        v = v + jnp.where(lane >= shift, roll(v, shift, 1), 0.0)
+        shift *= 2
+    return v
+
+
+def bin_amps_lane_major(x, cosp, sinp, rott, pre_re, pre_im, scale, *,
+                        win: int, k: int, roll=pltpu.roll):
+    """Shared v2 core: per-bin sliding amplitudes on ``[Bs, win]``
+    lane-major rows.  Yields ``(bin index, warm-up-scaled amp block,
+    last prefix rows re/im)``; the caller stores the last rows as the
+    next block's prefix state.  The K bins unroll as separate
+    ``[Bs, win]`` computations — the long window axis stays on lanes,
+    and the tables' padded sublane rows (k..KP-1) are never read.
+    ``cosp``/``sinp``/``rott``/``pre_*`` are kernel refs or, in the jnp
+    mirror (``roll=jnp.roll``), arrays: both index the same way.
     """
     for kk in range(k):
-        pr = jnp.cumsum(x * c_ref[kk:kk + 1, :], axis=1)      # [Bs, win]
-        pi = jnp.cumsum(x * (-s_ref[kk:kk + 1, :]), axis=1)
+        pr = _lane_cumsum(x * cosp[kk:kk + 1, :], roll)        # [Bs, win]
+        pi = _lane_cumsum(x * (-sinp[kk:kk + 1, :]), roll)
         # previous segment's prefix state: within the block the row
         # above; row 0 streams in from the previous grid cell's carry
-        prev_r = jnp.concatenate([pre_re[kk:kk + 1, :], pr[:-1]], axis=0)
-        prev_i = jnp.concatenate([pre_im[kk:kk + 1, :], pi[:-1]], axis=0)
+        prev_r = pre_re[kk:kk + 1, :]
+        prev_i = pre_im[kk:kk + 1, :]
+        if x.shape[0] > 1:
+            prev_r = jnp.concatenate([prev_r, pr[:-1]], axis=0)
+            prev_i = jnp.concatenate([prev_i, pi[:-1]], axis=0)
         # suffix of the previous segment = its total minus its prefix
         dr = prev_r[:, -1:] - prev_r
         di = prev_i[:, -1:] - prev_i
-        rr = r_ref[kk, 0]                 # cos(omega_k * win)
-        ri = r_ref[kk, 1]                 # sin(omega_k * win)
-        mr = pr + rr * dr - ri * di
-        mi = pi + rr * di + ri * dr
-        amp = (2.0 / win) * jnp.sqrt(mr * mr + mi * mi) * scale
-        pre_re[kk:kk + 1, :] = pr[-1:]
-        pre_im[kk:kk + 1, :] = pi[-1:]
-        yield kk, amp
+        rr = rott[kk, 0]                  # cos(omega_k * win)
+        ri = rott[kk, 1]                  # sin(omega_k * win)
+        mr = pr + _rounded(rr * dr) - _rounded(ri * di)
+        mi = pi + _rounded(rr * di) + _rounded(ri * dr)
+        amp = ((2.0 / win)
+               * jnp.sqrt(_rounded(mr * mr) + _rounded(mi * mi)) * scale)
+        yield kk, amp, pr[-1:], pi[-1:]
 
 
 def _global_idx_scale(x, s0, seg0, *, win: int):
     """Global sample index of every element of the [Bs, win] block (f32 —
     exact below 2**24 samples) and its warm-up renormalization.  ``seg0``
     is the global index of the call's first segment (0 offline; the
-    stream position for chunked carry calls)."""
+    stream position for chunked carry calls).  Mosaic lowers only
+    integer iotas, hence the int32 -> f32 conversion."""
     bs = x.shape[0]
-    segb = jax.lax.broadcasted_iota(jnp.float32, (bs, win), 0)
-    pos = jax.lax.broadcasted_iota(jnp.float32, (bs, win), 1)
-    idx = (seg0 + s0 * bs + segb) * win + pos
+    segb = jax.lax.broadcasted_iota(jnp.int32, (bs, win), 0)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (bs, win), 1)
+    idx = ((seg0 + (s0 * bs + segb).astype(jnp.float32)) * win
+           + pos.astype(jnp.float32))
     scale = float(win) / jnp.minimum(idx + 1.0, float(win))
     return idx, scale
 
@@ -237,10 +273,12 @@ def _sliding_kernel_v2(x_ref, cosp_ref, sinp_ref, rot_ref, par_ref,
 
     x = x_ref[...].astype(jnp.float32)                        # [Bs, win]
     _, scale = _global_idx_scale(x, s0, par_ref[0, 3], win=win)
-    for kk, amp in _bin_amps_lane_major(x, cosp_ref, sinp_ref, rot_ref,
-                                        pre_re, pre_im, scale,
-                                        win=win, k=k):
+    for kk, amp, last_r, last_i in bin_amps_lane_major(
+            x, cosp_ref, sinp_ref, rot_ref, pre_re, pre_im, scale,
+            win=win, k=k):
         o_refs[kk][...] = amp
+        pre_re[kk:kk + 1, :] = last_r
+        pre_im[kk:kk + 1, :] = last_i
     # every grid cell rewrites the same state block; the last write — the
     # final segment's prefix tables — is what the caller carries forward
     nre_ref[...] = pre_re[...]
@@ -321,11 +359,13 @@ def _monitor_kernel(x_ref, cosp_ref, sinp_ref, rot_ref, par_ref,
     live = (idx >= win - 1) & (idx < n)
     op_ref[...] = jnp.zeros_like(op_ref)      # padded bin columns stay 0
     worst = None
-    for kk, amp in _bin_amps_lane_major(x, cosp_ref, sinp_ref, rot_ref,
-                                        pre_re, pre_im, scale,
-                                        win=win, k=k):
+    for kk, amp, last_r, last_i in bin_amps_lane_major(
+            x, cosp_ref, sinp_ref, rot_ref, pre_re, pre_im, scale,
+            win=win, k=k):
         op_ref[:, kk] = jnp.where(live, amp, 0.0).max(axis=1)
         worst = amp if worst is None else jnp.maximum(worst, amp)
+        pre_re[kk:kk + 1, :] = last_r
+        pre_im[kk:kk + 1, :] = last_i
     # escalation_classify, inlined on the in-VMEM worst block
     hit = (worst > thr) & live
     clear = jnp.logical_not((worst > rel) & live)

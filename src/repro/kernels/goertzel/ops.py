@@ -29,7 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.goertzel.goertzel import (goertzel_pallas,
+from repro.kernels.goertzel.goertzel import (bin_amps_lane_major,
+                                             goertzel_pallas,
                                              sliding_goertzel_pallas,
                                              sliding_goertzel_v2_pallas,
                                              sliding_monitor_pallas)
@@ -142,6 +143,21 @@ def _params_row(threshold, release, n, seg0) -> jax.Array:
                       for v in vals]).reshape(1, 4)
 
 
+@jax.jit
+def trace_mean(x: jax.Array) -> jax.Array:
+    """The monitor's f32 DC operating point: the first sample plus the
+    mean of the residual about it.  The offline monitor removes exactly
+    this value in-graph; pass ``float(trace_mean(x_full))`` to
+    ``sliding_carry_init(mean=...)`` when chunked output must match the
+    offline call bitwise.  A plain f32 mean of a 5e8 W trace is off by
+    hundreds of watts, and by a different amount in every program that
+    reduces it in another order; the residual's mean is at oscillation
+    scale, so its rounding stays far below one ulp of the operating
+    point, and a constant trace gives exactly its own value."""
+    x = jnp.asarray(x, jnp.float32)
+    return x[0] + jnp.mean(x - x[0])
+
+
 @functools.partial(jax.jit,
                    static_argnames=("dt", "freqs", "win", "block_s",
                                     "interpret"))
@@ -151,7 +167,7 @@ def _sliding_bin_power_full(x: jax.Array, dt: float, freqs, *, win: int,
     """Whole-trace sliding monitor (see ``sliding_bin_power``)."""
     x = jnp.asarray(x, jnp.float32)
     n = x.shape[0]
-    xc = x - jnp.mean(x)
+    xc = x - trace_mean(x)
     S = -(-n // win)
     if block_s <= 0:
         # a few segments per grid cell amortizes cell overhead while the
@@ -215,17 +231,9 @@ def sliding_carry_init(dt: float, freqs, *, win: int,
                         mean=float(np.float32(mean)))
 
 
-@jax.jit
-def trace_mean(x: jax.Array) -> jax.Array:
-    """f32 mean of a trace, computed exactly as the offline monitor's
-    in-graph ``jnp.mean`` — use for ``sliding_carry_init(mean=...)``
-    when chunked output must match the offline call bitwise."""
-    return jnp.mean(jnp.asarray(x, jnp.float32))
-
-
 @functools.partial(jax.jit, static_argnames=("win", "k", "interpret"))
 def _sliding_seg_v2(seg, prev_re, prev_im, cosp, sinp, rott, seg0, *,
-                    win: int, k: int, interpret: bool = True):
+                    win: int, k: int, interpret: bool):
     """One segment of the sliding monitor *on the v2 Pallas kernel*
     (single-segment grid, carried prefix state streamed in/out) — the
     online carry path runs the same kernel program as the offline call,
@@ -290,10 +298,11 @@ def sliding_bin_power(x, dt: float, freqs, *, win: int, block_s: int = 0,
     must be a hashable static sequence of Hz; ``dt``/``win`` static).
 
     Semantics match the corrected float64 oracle
-    (``ref.sliding_bin_power_ref``): the trace mean is removed before
-    accumulation — see ``ref.py`` for the numerics rationale — and the
-    first ``win - 1`` outputs are partial-window estimates normalized by
-    the true sample count (the warm-up ramp is applied *in-kernel*).
+    (``ref.sliding_bin_power_ref``): the trace's DC operating point
+    (``trace_mean``) is removed before accumulation — see ``ref.py`` for
+    the numerics rationale — and the first ``win - 1`` outputs are
+    partial-window estimates normalized by the true sample count (the
+    warm-up ramp is applied *in-kernel*).
     The phase tables are built in float64 on the host, so bin phases
     stay exact at any trace length.  ``block_s=0`` picks a segment block
     size automatically; ``interpret=None`` compiles on TPU backends and
@@ -325,11 +334,10 @@ def sliding_bin_power(x, dt: float, freqs, *, win: int, block_s: int = 0,
 def _monitor_scan_jnp(xseg, cosp, sinp, rott, params, re0, im0, *,
                       win: int, k: int):
     """jnp mirror of ``sliding_monitor_pallas``: one ``lax.scan`` over
-    segments whose body is structurally identical to the kernel at
-    ``block_s=1`` — XLA's fused (FMA-contracted) evaluation of this
-    exact op graph is what the interpret-mode kernel lowers to, so the
-    two are *bitwise* equal (pinned in tests/test_kernels.py).  Must
-    stay jitted: an eager evaluation differs by 1 ulp."""
+    segments whose body runs the kernel's own core
+    (``bin_amps_lane_major``, with ``jnp.roll`` for the lane rolls), so
+    it is *bitwise* equal to the interpret-mode kernel at any
+    ``block_s`` (pinned in tests/test_kernels.py)."""
     S = xseg.shape[0]
     kp = cosp.shape[0]
     thr, rel, n, seg0 = (params[0, i] for i in range(4))
@@ -344,22 +352,13 @@ def _monitor_scan_jnp(xseg, cosp, sinp, rott, params, re0, im0, *,
         live = (idx >= win - 1) & (idx < n)
         worst = None
         nre, nim, ppk = [], [], []
-        for kk in range(k):
-            pr = jnp.cumsum(x * cosp[kk:kk + 1, :], axis=1)
-            pi = jnp.cumsum(x * (-sinp[kk:kk + 1, :]), axis=1)
-            prev_r = jnp.concatenate([pre_re[kk:kk + 1, :], pr[:-1]], axis=0)
-            prev_i = jnp.concatenate([pre_im[kk:kk + 1, :], pi[:-1]], axis=0)
-            dr = prev_r[:, -1:] - prev_r
-            di = prev_i[:, -1:] - prev_i
-            rr = rott[kk, 0]
-            ri = rott[kk, 1]
-            mr = pr + rr * dr - ri * di
-            mi = pi + rr * di + ri * dr
-            amp = (2.0 / win) * jnp.sqrt(mr * mr + mi * mi) * scale
+        for _, amp, last_r, last_i in bin_amps_lane_major(
+                x, cosp, sinp, rott, pre_re, pre_im, scale, win=win, k=k,
+                roll=jnp.roll):
             ppk.append(jnp.where(live, amp, 0.0).max(axis=1))
             worst = amp if worst is None else jnp.maximum(worst, amp)
-            nre.append(pr[-1:])
-            nim.append(pi[-1:])
+            nre.append(last_r)
+            nim.append(last_i)
         hit = (worst > thr) & live
         clear = jnp.logical_not((worst > rel) & live)
         band = jnp.logical_and(~hit, ~clear)
@@ -398,8 +397,8 @@ def monitor_carry_init(dt: float, freqs, *, win: int,
 @functools.partial(jax.jit, static_argnames=("win", "k", "interpret",
                                              "use_pallas"))
 def _monitor_seg_v2(seg, prev_re, prev_im, cosp, sinp, rott, params, *,
-                    win: int, k: int, interpret: bool = True,
-                    use_pallas: bool = True):
+                    win: int, k: int, interpret: bool,
+                    use_pallas: bool):
     """One segment of the fused monitor (single-segment grid) — the
     online fused path.  Returns (worst [win], cls [win], peaks [KP],
     new prefix tables)."""
@@ -467,7 +466,7 @@ def _sliding_monitor_full(x, threshold, release, dt: float, freqs, *,
     x = jnp.asarray(x, jnp.float32)
     n = x.shape[0]
     k = len(freqs)
-    xc = x - jnp.mean(x)
+    xc = x - trace_mean(x)
     S = -(-n // win)
     if block_s <= 0:
         block_s = max(1, min(8, S))

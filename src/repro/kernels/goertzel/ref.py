@@ -71,7 +71,10 @@ def sliding_bin_power_jnp(x: jnp.ndarray, dt: float, freqs,
     """
     x = jnp.asarray(x, jnp.float32)
     n = x.shape[-1]
-    xc = x - jnp.mean(x)            # DC removal: see module docstring
+    # DC removal (see module docstring) at the f32 operating point of
+    # ``ops.trace_mean``: centred on the first sample, so a constant
+    # trace leaves exactly zero
+    xc = x - (x[0] + jnp.mean(x - x[0]))
     # phases stay in-graph: a global-phase table is [n, K] (vs the Pallas
     # kernel's [win, K] host-precomputed tables) — materializing it as a
     # constant would bake tens of MB into the executable per trace length.

@@ -16,10 +16,14 @@ nothing drove before:
 * ``launch_workers()`` / ``worker_env()`` / ``free_port()`` — the
   subprocess-simulated multi-process harness (2 CPU processes are
   sufficient proof; the same env contract drives real multi-host).
+  ``launch_workers`` pins its workers to the CPU: a chip belongs to one
+  process, so simulated hosts never ask for it.
 * ``python -m repro.parallel.distributed --smoke`` — CI entry: runs a
   small Study single-process, re-runs it under 2 ``jax.distributed``
   processes on the scenario mesh, and asserts the two ``StudyResult``
-  record streams are bit-identical.
+  record streams are bit-identical.  The parent and its workers are all
+  pinned to the CPU (``pin_cpu``), so the comparison is CPU against CPU
+  and no process holds a chip another one needs.
 
 Process identity (``process_index``/``process_count``) is a *host-side*
 constant: compute it outside jit and pass values in.  Baking it into
@@ -80,6 +84,18 @@ def initialize(coordinator_address: Optional[str] = None,
     return True
 
 
+def pin_cpu() -> None:
+    """Keep this process's JAX on the CPU backend.  For the parent of a
+    simulated multi-host run: it must call this before its first JAX
+    computation, since a backend, once started, stays."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "JAX already started on a non-CPU backend; the simulated "
+            "multi-host harness needs this process on the CPU")
+
+
 def process_index() -> int:
     import jax
     return int(jax.process_index())
@@ -135,15 +151,17 @@ def launch_workers(argv: Sequence[str], num_processes: int = 2, *,
                    ) -> List[subprocess.CompletedProcess]:
     """Run ``num_processes`` copies of ``argv`` as one ``jax.distributed``
     job (shared fresh coordinator port, per-process id) and wait for all.
+    Every worker runs on the CPU backend (``JAX_PLATFORMS=cpu``).
     Raises if any worker exits non-zero, with that worker's stderr tail.
     """
     coord = f"localhost:{free_port()}"
-    procs = [subprocess.Popen(
-        list(argv), env=worker_env(env, coordinator=coord,
-                                   num_processes=num_processes,
-                                   process_id=pid),
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
-        for pid in range(num_processes)]
+    envs = [worker_env(env, coordinator=coord, num_processes=num_processes,
+                       process_id=pid) for pid in range(num_processes)]
+    for e in envs:
+        e["JAX_PLATFORMS"] = "cpu"
+    procs = [subprocess.Popen(list(argv), env=e, stdout=subprocess.PIPE,
+                              stderr=subprocess.PIPE, text=True)
+             for e in envs]
     done = []
     for pid, p in enumerate(procs):
         try:
@@ -195,6 +213,7 @@ def _smoke_worker(out_path: str, stream: int) -> None:
 
 
 def run_smoke(num_processes: int = 2, stream: int = 5) -> None:
+    pin_cpu()
     ref = _smoke_study().run(stream=stream)
     with tempfile.TemporaryDirectory() as td:
         out = os.path.join(td, "dist_records.json")

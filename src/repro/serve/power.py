@@ -676,6 +676,8 @@ def _watch_main(argv: Sequence[str]) -> None:
 
 
 def main(argv: Optional[Sequence[str]] = None) -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     argv = list(sys.argv[1:] if argv is None else argv)
     if argv and argv[0] == "watch":
         return _watch_main(argv[1:])
