@@ -24,6 +24,7 @@ from typing import List, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.control.detector import DetectorFrame
 from repro.core.telemetry import escalation_init, escalation_step
 
@@ -70,23 +71,25 @@ class GridController:
         self._carries: List[Tuple] = [escalation_init() for _ in self.freqs]
 
     def decide(self, frame: DetectorFrame) -> ControlDecision:
-        cfg = self.cfg
-        amps_eff = frame.amps + np.maximum(frame.slopes, 0.0) * cfg.lead_s
-        levels = np.zeros(len(self.freqs), np.int32)
-        for k in range(len(self.freqs)):
-            carry, level = escalation_step(
-                self._carries[k], jnp.float32(amps_eff[k]),
-                jnp.int32(frame.sample_idx),
-                threshold=cfg.trigger_w, win=self.win, n=_NO_PAD,
-                sustain_n=cfg.sustain_ticks, cool_n=cfg.release_ticks,
-                max_level=cfg.max_level, release=cfg.release_w)
-            self._carries[k] = carry
-            levels[k] = int(level)
-        margins = cfg.trigger_w - amps_eff
-        # worst bin: highest level, margin as the tiebreak
-        worst = int(np.lexsort((margins, -levels))[0])
-        return ControlDecision(tick=frame.tick, t_s=frame.t_s, levels=levels,
-                               target_level=int(levels.max()),
-                               amps_eff=np.asarray(amps_eff, np.float32),
-                               margins_w=np.asarray(margins, np.float32),
-                               worst_bin=worst)
+        with obs.span("repro.controller.decide"):
+            cfg = self.cfg
+            amps_eff = (frame.amps
+                        + np.maximum(frame.slopes, 0.0) * cfg.lead_s)
+            levels = np.zeros(len(self.freqs), np.int32)
+            for k in range(len(self.freqs)):
+                carry, level = escalation_step(
+                    self._carries[k], jnp.float32(amps_eff[k]),
+                    jnp.int32(frame.sample_idx),
+                    threshold=cfg.trigger_w, win=self.win, n=_NO_PAD,
+                    sustain_n=cfg.sustain_ticks, cool_n=cfg.release_ticks,
+                    max_level=cfg.max_level, release=cfg.release_w)
+                self._carries[k] = carry
+                levels[k] = int(level)
+            margins = cfg.trigger_w - amps_eff
+            # worst bin: highest level, margin as the tiebreak
+            worst = int(np.lexsort((margins, -levels))[0])
+            return ControlDecision(
+                tick=frame.tick, t_s=frame.t_s, levels=levels,
+                target_level=int(levels.max()),
+                amps_eff=np.asarray(amps_eff, np.float32),
+                margins_w=np.asarray(margins, np.float32), worst_bin=worst)

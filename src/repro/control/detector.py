@@ -30,6 +30,7 @@ from typing import Deque, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.kernels.goertzel.ops import (monitor_carry_init, sliding_bin_power,
                                         sliding_carry_init,
                                         sliding_monitor_fused)
@@ -102,40 +103,42 @@ class OnlineGoertzelDetector:
         return len(self.freqs)
 
     def step(self, chunk: np.ndarray) -> DetectorFrame:
-        tick_amps = tick_worst = None
-        level = 0
-        if self.fused:
-            worst, levels, latest, self.carry = sliding_monitor_fused(
-                chunk, self.dt, self.freqs, win=self.win,
-                threshold=self.threshold_w, release=self.release_w,
-                sustain_n=self.sustain_n, cool_n=self.cool_n,
-                max_level=self.max_level, carry=self.carry)
-            tick_worst = np.asarray(worst, np.float32)
-            level = int(levels[-1]) if len(levels) else int(self.carry.esc[0])
-            offset = int(self.carry.sliding.offset)
-        else:
-            amps, self.carry = sliding_bin_power(chunk, self.dt, self.freqs,
-                                                 win=self.win,
-                                                 carry=self.carry)
-            tick_amps = np.asarray(amps, np.float32)
-            latest = (amps[-1] if len(amps)
+        with obs.span("repro.detector.step"):
+            tick_amps = tick_worst = None
+            level = 0
+            if self.fused:
+                worst, levels, latest, self.carry = sliding_monitor_fused(
+                    chunk, self.dt, self.freqs, win=self.win,
+                    threshold=self.threshold_w, release=self.release_w,
+                    sustain_n=self.sustain_n, cool_n=self.cool_n,
+                    max_level=self.max_level, carry=self.carry)
+                tick_worst = np.asarray(worst, np.float32)
+                level = (int(levels[-1]) if len(levels)
+                         else int(self.carry.esc[0]))
+                offset = int(self.carry.sliding.offset)
+            else:
+                amps, self.carry = sliding_bin_power(
+                    chunk, self.dt, self.freqs, win=self.win,
+                    carry=self.carry)
+                tick_amps = np.asarray(amps, np.float32)
+                latest = (amps[-1] if len(amps)
+                          else np.zeros(self.n_bins, np.float32))
+                offset = int(self.carry.offset)
+            last_idx = offset - 1
+            t_s = last_idx * self.dt
+            self._hist.append((t_s, latest))
+            while (len(self._hist) > 2
+                   and t_s - self._hist[0][0] > self._horizon_s):
+                self._hist.popleft()
+            t0, a0 = self._hist[0]
+            span = t_s - t0
+            slopes = ((latest - a0) / span if span > 0
                       else np.zeros(self.n_bins, np.float32))
-            offset = int(self.carry.offset)
-        last_idx = offset - 1
-        t_s = last_idx * self.dt
-        self._hist.append((t_s, latest))
-        while (len(self._hist) > 2
-               and t_s - self._hist[0][0] > self._horizon_s):
-            self._hist.popleft()
-        t0, a0 = self._hist[0]
-        span = t_s - t0
-        slopes = ((latest - a0) / span if span > 0
-                  else np.zeros(self.n_bins, np.float32))
-        frame = DetectorFrame(tick=self._tick, t_s=t_s, sample_idx=last_idx,
-                              amps=np.asarray(latest, np.float32),
-                              slopes=np.asarray(slopes, np.float32),
-                              warm=last_idx >= self.win - 1,
-                              tick_amps=tick_amps, tick_worst=tick_worst,
-                              level=level)
-        self._tick += 1
-        return frame
+            frame = DetectorFrame(
+                tick=self._tick, t_s=t_s, sample_idx=last_idx,
+                amps=np.asarray(latest, np.float32),
+                slopes=np.asarray(slopes, np.float32),
+                warm=last_idx >= self.win - 1,
+                tick_amps=tick_amps, tick_worst=tick_worst, level=level)
+            self._tick += 1
+            return frame

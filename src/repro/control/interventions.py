@@ -28,12 +28,12 @@ Emerald Conductor escalates orchestrator actions.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable, Dict, Optional
 
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.ballast_inject import ballast_gflops_for_floor
 from repro.core.engine import design
 from repro.core.hardware import DEFAULT_HW, Hardware
@@ -47,7 +47,6 @@ class Intervention:
     name: str
     params: Dict
     transform: Callable[[np.ndarray, float], np.ndarray]
-    build_latency_s: float = 0.0
 
 
 def redesign_intervention(spec, history_w: np.ndarray, dt: float,
@@ -64,10 +63,8 @@ def redesign_intervention(spec, history_w: np.ndarray, dt: float,
     w = np.asarray(history_w, np.float32)
     mean = float(w.mean())
     target = (mean + headroom * (w - mean)).astype(np.float32)
-    t0 = time.perf_counter()
     sol = design(spec, target, dt, n_chips, method=method, hw=hw,
                  warmstart=warmstart)
-    latency = time.perf_counter() - t0
     if sol is None:
         return None
     gpu = sol.get("device_mitigation")
@@ -92,7 +89,7 @@ def redesign_intervention(spec, history_w: np.ndarray, dt: float,
                 "energy_overhead": float(sol.get("energy_overhead", 0.0)),
                 "method": sol.get("method", method),
                 "headroom": headroom},
-        transform=transform, build_latency_s=latency)
+        transform=transform)
 
 
 def power_cap_intervention(history_w: np.ndarray, dt: float, *,
@@ -185,29 +182,32 @@ class InterventionLadder:
         self.headroom = headroom
         self.stagger_groups = int(stagger_groups)
         self._cache: Dict[int, Optional[Intervention]] = {}
+        #: wall-clock seconds of each cached rung's build, failed ones too
+        self.build_latency_s: Dict[int, float] = {}
 
     def build(self, rung: int, history_w: np.ndarray,
               f_hz: float) -> Optional[Intervention]:
-        """Build (or fetch) the intervention for ladder rung 1..3,
-        measuring wall-clock build latency."""
-        if rung in self._cache:
-            return self._cache[rung]
-        t0 = time.perf_counter()
-        if rung == 1:
-            iv = redesign_intervention(
-                self.spec, history_w, self.dt, self.n_chips, hw=self.hw,
-                method=self.design_method, warmstart=self.warmstart,
-                headroom=self.headroom)
-        elif rung == 2:
-            iv = power_cap_intervention(
-                history_w, self.dt, release_amp_w=self.release_amp_w,
-                n_chips=self.n_chips, hw=self.hw)
-        else:
-            iv = stagger_intervention(f_hz, self.dt,
-                                      n_groups=self.stagger_groups,
-                                      history_w=history_w)
-        if iv is not None:
-            iv.build_latency_s = time.perf_counter() - t0
+        """Build (or fetch) the intervention for ladder rung 1..3.  A
+        fresh build's wall-clock latency, its ``repro.ladder.build``
+        span's duration, is kept in ``build_latency_s[rung]``."""
+        cached = rung in self._cache
+        with obs.span("repro.ladder.build", rung=rung, cached=cached) as sp:
+            if cached:
+                return self._cache[rung]
+            if rung == 1:
+                iv = redesign_intervention(
+                    self.spec, history_w, self.dt, self.n_chips, hw=self.hw,
+                    method=self.design_method, warmstart=self.warmstart,
+                    headroom=self.headroom)
+            elif rung == 2:
+                iv = power_cap_intervention(
+                    history_w, self.dt, release_amp_w=self.release_amp_w,
+                    n_chips=self.n_chips, hw=self.hw)
+            else:
+                iv = stagger_intervention(f_hz, self.dt,
+                                          n_groups=self.stagger_groups,
+                                          history_w=history_w)
+        self.build_latency_s[rung] = sp.duration_s
         self._cache[rung] = iv
         return iv
 
@@ -215,3 +215,4 @@ class InterventionLadder:
         """Forget a rung's cached config so a future re-escalation
         re-solves against fresh history."""
         self._cache.pop(rung, None)
+        self.build_latency_s.pop(rung, None)

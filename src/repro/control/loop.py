@@ -15,11 +15,11 @@ tests.
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.control.controller import (ControlDecision, ControllerConfig,
                                       GridController)
 from repro.control.detector import OnlineGoertzelDetector
@@ -61,6 +61,8 @@ class ControlLoop:
         self.applied_level = 0
         self.active: Dict[int, object] = {}       # rung -> Intervention
         self._due: Optional[int] = None           # tick the dispatch is due
+        # the spans of every tick of this loop share one trace id
+        self.trace_id = obs.new_trace_id()
 
     # -- dispatch -----------------------------------------------------------
 
@@ -68,7 +70,6 @@ class ControlLoop:
         target = decision.target_level
         f_hz = self.controller.freqs[decision.worst_bin]
         history = self.source.history(self.history_n)
-        t0 = time.perf_counter()
         for rung in range(1, target + 1):
             if rung in self.active:
                 continue
@@ -81,7 +82,7 @@ class ControlLoop:
                     bin_hz=f_hz,
                     amplitude_w=float(decision.amps_eff[decision.worst_bin]),
                     margin_w=float(decision.margins_w[decision.worst_bin]),
-                    latency_s=time.perf_counter() - t0)
+                    latency_s=self.ladder.build_latency_s[rung])
                 continue
             self.active[rung] = iv
             self.log.record(
@@ -89,7 +90,8 @@ class ControlLoop:
                 action=f"dispatch:{iv.name}", level=target, bin_hz=f_hz,
                 amplitude_w=float(decision.amps_eff[decision.worst_bin]),
                 margin_w=float(decision.margins_w[decision.worst_bin]),
-                latency_s=iv.build_latency_s, params=dict(iv.params))
+                latency_s=self.ladder.build_latency_s[rung],
+                params=dict(iv.params))
         for rung in [r for r in self.active if r > target]:
             iv = self.active.pop(rung)
             self.ladder.release(rung)
@@ -98,8 +100,9 @@ class ControlLoop:
                 action=f"release:{iv.name}", level=target, bin_hz=f_hz,
                 amplitude_w=float(decision.amps_eff[decision.worst_bin]),
                 margin_w=float(decision.margins_w[decision.worst_bin]))
-        self.source.apply_interventions(
-            [self.active[r] for r in sorted(self.active)])
+        with obs.span("repro.source.apply", n=len(self.active)):
+            self.source.apply_interventions(
+                [self.active[r] for r in sorted(self.active)])
         self.applied_level = target
 
     # -- the loop -----------------------------------------------------------
@@ -107,30 +110,35 @@ class ControlLoop:
     def run(self, max_ticks: Optional[int] = None) -> ControlLog:
         ticks = 0
         while max_ticks is None or ticks < max_ticks:
-            chunk = self.source.next_tick()
-            if chunk is None:
-                break
-            frame = self.detector.step(chunk)
-            decision = self.controller.decide(frame)
-            self.log.sample(tick=frame.tick, t_s=frame.t_s,
-                            level=decision.target_level, amps=frame.amps,
-                            amps_eff=decision.amps_eff)
-            target = decision.target_level
-            if target != self.applied_level:
-                if target > self.applied_level and self._due is None:
-                    k = decision.worst_bin
-                    self.log.record(
-                        tick=frame.tick, t_s=frame.t_s, action="escalate",
-                        level=target, bin_hz=self.controller.freqs[k],
-                        amplitude_w=float(decision.amps_eff[k]),
-                        margin_w=float(decision.margins_w[k]))
-                if self._due is None:
-                    self._due = frame.tick + self.dispatch_ticks - 1
-                if frame.tick >= self._due:
-                    self._dispatch(decision)
+            with obs.span("repro.control.tick",
+                          trace_id=self.trace_id) as tick:
+                with obs.span("repro.source.next"):
+                    chunk = self.source.next_tick()
+                if chunk is None:
+                    break
+                frame = self.detector.step(chunk)
+                tick.attrs["tick"] = frame.tick
+                decision = self.controller.decide(frame)
+                self.log.sample(tick=frame.tick, t_s=frame.t_s,
+                                level=decision.target_level, amps=frame.amps,
+                                amps_eff=decision.amps_eff)
+                target = decision.target_level
+                if target != self.applied_level:
+                    if target > self.applied_level and self._due is None:
+                        k = decision.worst_bin
+                        self.log.record(
+                            tick=frame.tick, t_s=frame.t_s, action="escalate",
+                            level=target, bin_hz=self.controller.freqs[k],
+                            amplitude_w=float(decision.amps_eff[k]),
+                            margin_w=float(decision.margins_w[k]))
+                    if self._due is None:
+                        self._due = frame.tick + self.dispatch_ticks - 1
+                    if frame.tick >= self._due:
+                        with obs.span("repro.control.dispatch", level=target):
+                            self._dispatch(decision)
+                        self._due = None
+                else:
                     self._due = None
-            else:
-                self._due = None
             ticks += 1
         return self.log
 

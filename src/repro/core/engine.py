@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.hardware import DEFAULT_HW, Hardware
 from repro.core.optim import adam_init, adam_update, clip_by_global_norm
 from repro.core.phases import IterationTimeline
@@ -504,77 +505,89 @@ def simulate_batch(
      B) = _prepare_rows(timelines, n_chips, seeds, device_mitigation,
                         rack_mitigation, levels, cfg, hw)
 
-    src_ids = [id(r) for r in level_rows]   # pre-padding row identity
-    n_valid_arr = None
-    if pad_to is not None:
-        if spec is not None or spectra:
-            raise ValueError(
-                "pad_to defers frequency/spec analysis to analyze_batch on "
-                "the sliced rows: call with spec=None, spectra=False")
-        lens = [len(r) for r in level_rows]
-        if max(lens) > pad_to:
-            raise ValueError(f"pad_to={pad_to} < longest workload {max(lens)}")
-        n_valid_arr = jnp.asarray(lens, jnp.float32)
-        level_rows = [np.pad(r, (0, pad_to - len(r)), mode="edge")
-                      for r in level_rows]
-    else:
-        n0 = len(level_rows[0])
-        if any(len(r) != n0 for r in level_rows):
-            raise ValueError(
-                "all timelines in one simulate_batch call must expand to the "
-                f"same sample count (got {sorted({len(r) for r in level_rows})}); "
-                "use sweep()/Study to bucket, or pad_to to fuse")
-    n = len(level_rows[0])
-    shifts = jnp.asarray(np.stack(
-        [jitter_shifts(cfg, s, sample_chips) for s in seed_list]))
-    chips_f = jnp.asarray(np.asarray(chips, np.float32))
-    dev, dev_on = _normalize_mits(dev_list, B, "device_mitigation")
-    rack, rack_on = _normalize_mits(rack_list, B, "rack_mitigation")
-    keys_arr = _normalize_keys(keys, B)
-    # family/limits split: the spec's *structure* is the static jit key,
-    # its numeric thresholds ride in as traced scalars — every same-family
-    # spec (lenient/moderate/tight at any job power) shares one executable
-    family = None if spec is None else spec.family()
-    limits = None if spec is None else spec.limits()
+    with obs.span("repro.engine.inputs"):
+        src_ids = [id(r) for r in level_rows]   # pre-padding row identity
+        n_valid_arr = None
+        if pad_to is not None:
+            if spec is not None or spectra:
+                raise ValueError(
+                    "pad_to defers frequency/spec analysis to analyze_batch "
+                    "on the sliced rows: call with spec=None, spectra=False")
+            lens = [len(r) for r in level_rows]
+            if max(lens) > pad_to:
+                raise ValueError(
+                    f"pad_to={pad_to} < longest workload {max(lens)}")
+            n_valid_arr = jnp.asarray(lens, jnp.float32)
+            level_rows = [np.pad(r, (0, pad_to - len(r)), mode="edge")
+                          for r in level_rows]
+        else:
+            n0 = len(level_rows[0])
+            if any(len(r) != n0 for r in level_rows):
+                raise ValueError(
+                    "all timelines in one simulate_batch call must expand to "
+                    "the same sample count (got "
+                    f"{sorted({len(r) for r in level_rows})}); use "
+                    "sweep()/Study to bucket, or pad_to to fuse")
+        n = len(level_rows[0])
+        shifts = jnp.asarray(np.stack(
+            [jitter_shifts(cfg, s, sample_chips) for s in seed_list]))
+        chips_f = jnp.asarray(np.asarray(chips, np.float32))
+        keys_arr = _normalize_keys(keys, B)
+        # family/limits split: the spec's *structure* is the static jit
+        # key, its numeric thresholds ride in as traced scalars — every
+        # same-family spec (lenient/moderate/tight at any job power)
+        # shares one executable
+        family = None if spec is None else spec.family()
+        limits = None if spec is None else spec.limits()
+        shard = _resolve_plan(plan, shard_devices)
+        if dedup:
+            # synthesis once per unique (workload, fleet, seed); the
+            # per-config suffix gathers its prefix by index
+            uniq: Dict[Tuple, int] = {}
+            u_rows: List[int] = []
+            u_idx: List[int] = []
+            for i, k in enumerate(zip(src_ids, chips, seed_list)):
+                if k not in uniq:
+                    uniq[k] = len(u_rows)
+                    u_rows.append(i)
+                u_idx.append(uniq[k])
+            sel = np.asarray(u_rows)
+            synth_in = (jnp.asarray(np.stack([level_rows[i] for i in u_rows]),
+                                    jnp.float32),
+                        shifts[sel], chips_f[sel],
+                        None if n_valid_arr is None else n_valid_arr[sel])
+            u_idx = jnp.asarray(u_idx, jnp.int32)
+    with obs.span("repro.engine.stack_mits", stage="device"):
+        dev, dev_on = _normalize_mits(dev_list, B, "device_mitigation")
+    with obs.span("repro.engine.stack_mits", stage="rack"):
+        rack, rack_on = _normalize_mits(rack_list, B, "rack_mitigation")
 
-    shard = _resolve_plan(plan, shard_devices)
     out_B = B
-    if dedup:
-        # synthesis once per unique (workload, fleet, seed); the per-config
-        # suffix gathers its prefix by index
-        uniq: Dict[Tuple, int] = {}
-        u_rows: List[int] = []
-        u_idx: List[int] = []
-        for i, k in enumerate(zip(src_ids, chips, seed_list)):
-            if k not in uniq:
-                uniq[k] = len(u_rows)
-                u_rows.append(i)
-            u_idx.append(uniq[k])
-        sel = np.asarray(u_rows)
-        synth_in = (jnp.asarray(np.stack([level_rows[i] for i in u_rows]),
-                                jnp.float32),
-                    shifts[sel], chips_f[sel],
-                    None if n_valid_arr is None else n_valid_arr[sel])
-        if shard is not None and shard.n_processes > 1:
-            # global arrays only compose with global arrays in one SPMD
-            # program: commit the unique-row prefix to the scenario mesh
-            # too (pad rows are duplicates no ``u_idx`` ever references)
-            synth_in, _ = shard.shard_batch(synth_in, len(u_rows))
-        chip_u, dcraw_u = _synth_vmapped(*synth_in, cfg=cfg, hw=hw)
-        row_args = (jnp.asarray(u_idx, jnp.int32), shifts, chips_f, dev,
-                    rack, dev_on, rack_on, keys_arr, n_valid_arr)
-        if shard is not None:
-            row_args, out_B = shard.shard_batch(row_args, B)
-        res = _mitigate_vmapped(chip_u, dcraw_u, *row_args, limits,
-                                cfg=cfg, hw=hw, spec=family, spectra=spectra,
-                                chip_outputs=chip_outputs, plan=shard)
-    else:
-        args = (jnp.asarray(np.stack(level_rows), jnp.float32), shifts,
-                chips_f, dev, rack, dev_on, rack_on, keys_arr, n_valid_arr)
-        if shard is not None:
-            args, out_B = shard.shard_batch(args, B)
-        res = _simulate_vmapped(*args, limits, cfg=cfg, hw=hw, spec=family,
-                                spectra=spectra, plan=shard)
+    with obs.span("repro.engine.enqueue"):
+        if dedup:
+            if shard is not None and shard.n_processes > 1:
+                # global arrays only compose with global arrays in one
+                # SPMD program: commit the unique-row prefix to the
+                # scenario mesh too (pad rows are duplicates no ``u_idx``
+                # ever references)
+                synth_in, _ = shard.shard_batch(synth_in, len(u_rows))
+            chip_u, dcraw_u = _synth_vmapped(*synth_in, cfg=cfg, hw=hw)
+            row_args = (u_idx, shifts, chips_f, dev, rack, dev_on, rack_on,
+                        keys_arr, n_valid_arr)
+            if shard is not None:
+                row_args, out_B = shard.shard_batch(row_args, B)
+            res = _mitigate_vmapped(chip_u, dcraw_u, *row_args, limits,
+                                    cfg=cfg, hw=hw, spec=family,
+                                    spectra=spectra,
+                                    chip_outputs=chip_outputs, plan=shard)
+        else:
+            args = (jnp.asarray(np.stack(level_rows), jnp.float32), shifts,
+                    chips_f, dev, rack, dev_on, rack_on, keys_arr,
+                    n_valid_arr)
+            if shard is not None:
+                args, out_B = shard.shard_batch(args, B)
+            res = _simulate_vmapped(*args, limits, cfg=cfg, hw=hw,
+                                    spec=family, spectra=spectra, plan=shard)
     if host_arrays:
         # single-process this is the plain np.asarray(+slice) host pull;
         # multi-process it is one replicate-all collective first
@@ -585,6 +598,11 @@ def simulate_batch(
         # keeps the shard padding too — an eager slice would re-replicate
         # the array; downstream gathers never touch the pad rows.
         res = jax.tree.map(lambda a: a[:B], res)
+    with obs.span("repro.stream.pull"):
+        n_valid = (None if n_valid_arr is None
+                   else np.asarray(n_valid_arr, np.int64))
+        dev_on = None if dev_on is None else np.asarray(dev_on) > 0
+        rack_on = None if rack_on is None else np.asarray(rack_on) > 0
     return BatchResult(
         t=np.arange(n) * cfg.dt,
         dc_raw=res["dc_raw"], dc_mitigated=res["dc_mitigated"],
@@ -595,10 +613,7 @@ def simulate_batch(
         bands=res.get("bands"), bands_mitigated=res.get("bands_mitigated"),
         spec_ok=res.get("spec_ok"), spec_flags=res.get("spec_flags"),
         spec_metrics=res.get("spec_metrics"), aux=res["aux"],
-        n_valid=(None if n_valid_arr is None
-                 else np.asarray(n_valid_arr, np.int64)),
-        dev_on=(None if dev_on is None else np.asarray(dev_on) > 0),
-        rack_on=(None if rack_on is None else np.asarray(rack_on) > 0))
+        n_valid=n_valid, dev_on=dev_on, rack_on=rack_on)
 
 
 # ---------------------------------------------------------------------------
@@ -713,25 +728,27 @@ def stream_batches(
     surviving chunks are bit-identical to the same chunks of a full run.
     """
     cfg = wave_cfg or WaveformConfig()
-    (tls, chips, seed_list, dev_list, rack_list, level_rows,
-     B) = _prepare_rows(timelines, n_chips, seeds, device_mitigation,
-                        rack_mitigation, levels, cfg, hw)
-    spec_list = list(specs) if isinstance(specs, (list, tuple)) else [specs]
-    # per-slot family/limits split, computed once for the whole stream
-    fam_lims = [(None, None) if sp is None else (sp.family(), sp.limits())
-                for sp in spec_list]
-    keys_arr = _normalize_keys(keys, B)
+    with obs.span("repro.stream.prepare") as prep:
+        (tls, chips, seed_list, dev_list, rack_list, level_rows,
+         B) = _prepare_rows(timelines, n_chips, seeds, device_mitigation,
+                            rack_mitigation, levels, cfg, hw)
+        prep.attrs["rows"] = B
+        spec_list = (list(specs) if isinstance(specs, (list, tuple))
+                     else [specs])
+        # per-slot family/limits split, computed once for the whole stream
+        fam_lims = [(None, None) if sp is None
+                    else (sp.family(), sp.limits()) for sp in spec_list]
+        keys_arr = _normalize_keys(keys, B)
 
-    lens = [len(r) for r in level_rows]
-    if pad_to is None and len(set(lens)) > 1:
-        pad_to = max(lens)
-    chunk_size = max(1, min(chunk_size, B))
-    n_chunks = -(-B // chunk_size)
-    shard = _resolve_plan(plan, shard_devices)
+        lens = [len(r) for r in level_rows]
+        if pad_to is None and len(set(lens)) > 1:
+            pad_to = max(lens)
+        chunk_size = max(1, min(chunk_size, B))
+        n_chunks = -(-B // chunk_size)
+        shard = _resolve_plan(plan, shard_devices)
 
-    def dispatch(lo: int, hi: int):
+    def dispatch(lo: int, hi: int, tail: int):
         C = hi - lo
-        tail = chunk_size - C if n_chunks > 1 else 0
 
         def sl(xs):
             return xs[lo:hi] + [xs[hi - 1]] * tail
@@ -756,40 +773,49 @@ def stream_batches(
         gres = []
         mult = (shard.n_shards
                 if shard is not None and shard.n_processes > 1 else 1)
-        for L, g in sorted(groups.items()):
-            # pow2 padding buys bounded compile counts across chunks; a
-            # single-chunk (one-shot) run has one fixed shape either way,
-            # so analyze at exact size and skip the wasted lanes
-            sel = list(_pow2_pad(g) if n_chunks > 1 else g)
-            if len(sel) % mult:
-                # multi-process analysis stays sharded: pad the gather to
-                # a shard multiple (pow2 sizes usually already are)
-                sel += [sel[-1]] * (mult - len(sel) % mult)
-            mit = gather_rows(res.dc_mitigated, sel, shard, length=L)
-            per_spec = []
-            for si, sp in enumerate(spec_list):
-                do_bands = bands and si == 0
-                if sp is None and not do_bands:
-                    per_spec.append(None)
-                    continue
-                fam, lim = fam_lims[si]
-                per_spec.append(_analyze_vmapped(None, mit, lim, spec=fam,
-                                                 dt=cfg.dt, bands=do_bands))
-            gres.append((g, per_spec))
+        with obs.span("repro.stream.analyze", groups=len(groups)):
+            for L, g in sorted(groups.items()):
+                # pow2 padding buys bounded compile counts across chunks;
+                # a single-chunk (one-shot) run has one fixed shape either
+                # way, so analyze at exact size and skip the wasted lanes
+                sel = list(_pow2_pad(g) if n_chunks > 1 else g)
+                if len(sel) % mult:
+                    # multi-process analysis stays sharded: pad the
+                    # gather to a shard multiple (pow2 sizes usually
+                    # already are)
+                    sel += [sel[-1]] * (mult - len(sel) % mult)
+                mit = gather_rows(res.dc_mitigated, sel, shard, length=L)
+                per_spec = []
+                for si, sp in enumerate(spec_list):
+                    do_bands = bands and si == 0
+                    if sp is None and not do_bands:
+                        per_spec.append(None)
+                        continue
+                    fam, lim = fam_lims[si]
+                    per_spec.append(_analyze_vmapped(
+                        None, mit, lim, spec=fam, dt=cfg.dt,
+                        bands=do_bands))
+                gres.append((g, per_spec))
         return lo, hi, res, gres
 
     def materialize(pending) -> StreamChunk:
         lo, hi, res, gres = pending
         C = hi - lo
         S = len(spec_list)
-        # one host pull for all per-row metric fields; multi-process this
-        # is the cross-process merge (replicate-all, then np.asarray)
-        direct = host_allgather(
-            {"eo": res.energy_overhead, "sw": res.swing,
-             "swm": res.swing_mitigated,
-             "raw": res.dc_raw if keep_waveforms else None,
-             "mit": res.dc_mitigated if keep_waveforms else None},
-            shard, take=C)
+        # the chunk's blocking wait: one host pull for all per-row metric
+        # fields, then one per analysis call; multi-process each is the
+        # cross-process merge (replicate-all, then np.asarray)
+        with obs.span("repro.stream.pull"):
+            direct = host_allgather(
+                {"eo": res.energy_overhead, "sw": res.swing,
+                 "swm": res.swing_mitigated,
+                 "raw": res.dc_raw if keep_waveforms else None,
+                 "mit": res.dc_mitigated if keep_waveforms else None},
+                shard, take=C)
+            gres = [(g, [None if a is None
+                         else host_allgather(a, shard, take=len(g))
+                         for a in per_spec])
+                    for g, per_spec in gres]
         chunk = StreamChunk(
             start=lo, stop=hi,
             n=res.dc_mitigated.shape[1],
@@ -803,11 +829,9 @@ def stream_batches(
             dc_raw=direct["raw"], dc_mitigated=direct["mit"])
         bands_cols: Dict[str, np.ndarray] = {}
         for g, per_spec in gres:
-            G = len(g)
             for si, a in enumerate(per_spec):
                 if a is None:
                     continue
-                a = host_allgather(a, shard, take=G)
                 if "bands_mitigated" in a:
                     for k, v in a["bands_mitigated"].items():
                         bands_cols.setdefault(
@@ -829,6 +853,11 @@ def stream_batches(
             chunk.bands_mitigated = bands_cols
         return chunk
 
+    def pulled(pending) -> StreamChunk:
+        with obs.span("repro.stream.materialize",
+                      rows=pending[1] - pending[0]):
+            return materialize(pending)
+
     if skip_rows % chunk_size and skip_rows < B:
         raise ValueError(
             f"skip_rows={skip_rows} is not a chunk boundary of "
@@ -838,12 +867,15 @@ def stream_batches(
         hi = min(lo + chunk_size, B)
         if hi <= skip_rows:
             continue
-        cur = dispatch(lo, hi)
+        tail = chunk_size - (hi - lo) if n_chunks > 1 else 0
+        with obs.span("repro.stream.dispatch", lo=lo, rows=hi - lo,
+                      tail_pad=tail):
+            cur = dispatch(lo, hi, tail)
         if pending is not None:
-            yield materialize(pending)
+            yield pulled(pending)
         pending = cur
     if pending is not None:
-        yield materialize(pending)
+        yield pulled(pending)
 
 
 # ---------------------------------------------------------------------------

@@ -66,6 +66,7 @@ from typing import (Callable, Dict, Iterator, List, Mapping, Optional,
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core.engine import StreamChunk, design, stream_batches
 from repro.core.hardware import DEFAULT_HW, Hardware
 from repro.core.phases import IterationTimeline
@@ -253,104 +254,111 @@ def run_rows(workloads: Mapping[str, IterationTimeline],
     emits — worker processes stay silent.  Rows restored from a resume
     dir are reported in one leading callback per call stream.
     """
-    cfg = wave_cfg or WaveformConfig()
-    if padding not in PADDING_MODES:
-        raise ValueError(f"padding must be one of {PADDING_MODES}")
-    if stream is None or stream is False:
-        chunk_size = None
-    elif stream is True:
-        chunk_size = DEFAULT_STREAM_CHUNK
-    else:
-        chunk_size = int(stream)
-        if chunk_size < 1:
-            raise ValueError(f"stream chunk size must be >= 1, got {stream}")
-    rows = list(rows)
-    specs = list(specs)
-    if levels is None:
-        levels = {}
-    needed = {w for w, _, _, _ in rows}
-    levels = dict(levels)
-    for w in needed:
-        if w not in levels:
-            levels[w] = phase_levels(workloads[w], cfg, hw)
-    row_len = [len(levels[w]) for w, _, _, _ in rows]
-    mode = padding
-    if mode == "auto":
-        mode = "pad" if len(set(row_len)) > 1 else "bucket"
-    if keys is not None:
-        keys = list(keys)
-        if len(keys) != len(rows):
-            raise ValueError(f"keys: got {len(keys)}, expected {len(rows)}")
-
-    primary = _is_primary()
-    ckpt = None
-    if resume is not None:
-        if chunk_size is None:
-            raise ValueError(
-                "resume= requires streaming (pass stream=True or stream=N): "
-                "chunk boundaries are the checkpoint points")
-        if keep_waveforms:
-            raise ValueError(
-                "resume= does not support keep_waveforms=True — waveforms "
-                "are not checkpointed, so a resumed result would miss them")
-        ckpt = SweepCheckpoint(resume)
-        ckpt.validate_or_init(
-            workloads=workloads, rows=rows, specs=specs, keys=keys,
-            cfg=cfg, hw=hw, mode=mode, sample_chips=sample_chips,
-            chunk_size=chunk_size, write=primary)
-
-    emit = on_chunk if (on_chunk is not None and primary) else None
-    cols = _empty_columns(len(rows) * len(specs))
-    waveforms = [None] * len(rows) if keep_waveforms else None
-    total, done = len(rows), 0
-    t0 = time.perf_counter()
-    for gi, sg_rows in enumerate(_structure_groups(rows)):
-        if mode == "pad":
-            calls = [(f"g{gi}-pad", sg_rows)]
+    with obs.span("repro.study.run") as run_span:
+        cfg = wave_cfg or WaveformConfig()
+        if padding not in PADDING_MODES:
+            raise ValueError(f"padding must be one of {PADDING_MODES}")
+        if stream is None or stream is False:
+            chunk_size = None
+        elif stream is True:
+            chunk_size = DEFAULT_STREAM_CHUNK
         else:
-            by_len: Dict[int, List[int]] = {}
-            for r in sg_rows:
-                by_len.setdefault(row_len[r], []).append(r)
-            calls = [(f"g{gi}-L{L}", idx)
-                     for L, idx in sorted(by_len.items())]
-        for call_key, idx in calls:
-            lens = {row_len[r] for r in idx}
-            cs_eff = max(1, min(chunk_size or len(idx), len(idx)))
-            skip = 0
-            if ckpt is not None:
-                skip = ckpt.restore_call(call_key, idx, cs_eff, cols,
-                                         len(specs))
-                if skip:
-                    done += skip
+            chunk_size = int(stream)
+            if chunk_size < 1:
+                raise ValueError(
+                    f"stream chunk size must be >= 1, got {stream}")
+        rows = list(rows)
+        run_span.attrs.update(rows=len(rows), chunk=chunk_size)
+        specs = list(specs)
+        if levels is None:
+            levels = {}
+        needed = {w for w, _, _, _ in rows}
+        levels = dict(levels)
+        for w in needed:
+            if w not in levels:
+                levels[w] = phase_levels(workloads[w], cfg, hw)
+        row_len = [len(levels[w]) for w, _, _, _ in rows]
+        mode = padding
+        if mode == "auto":
+            mode = "pad" if len(set(row_len)) > 1 else "bucket"
+        if keys is not None:
+            keys = list(keys)
+            if len(keys) != len(rows):
+                raise ValueError(
+                    f"keys: got {len(keys)}, expected {len(rows)}")
+
+        primary = _is_primary()
+        ckpt = None
+        if resume is not None:
+            if chunk_size is None:
+                raise ValueError(
+                    "resume= requires streaming (pass stream=True or "
+                    "stream=N): chunk boundaries are the checkpoint points")
+            if keep_waveforms:
+                raise ValueError(
+                    "resume= does not support keep_waveforms=True — "
+                    "waveforms are not checkpointed, so a resumed result "
+                    "would miss them")
+            ckpt = SweepCheckpoint(resume)
+            ckpt.validate_or_init(
+                workloads=workloads, rows=rows, specs=specs, keys=keys,
+                cfg=cfg, hw=hw, mode=mode, sample_chips=sample_chips,
+                chunk_size=chunk_size, write=primary)
+
+        emit = on_chunk if (on_chunk is not None and primary) else None
+        cols = _empty_columns(len(rows) * len(specs))
+        waveforms = [None] * len(rows) if keep_waveforms else None
+        total, done = len(rows), 0
+        t0 = time.perf_counter()
+        for gi, sg_rows in enumerate(_structure_groups(rows)):
+            if mode == "pad":
+                calls = [(f"g{gi}-pad", sg_rows)]
+            else:
+                by_len: Dict[int, List[int]] = {}
+                for r in sg_rows:
+                    by_len.setdefault(row_len[r], []).append(r)
+                calls = [(f"g{gi}-L{L}", idx)
+                         for L, idx in sorted(by_len.items())]
+            for call_key, idx in calls:
+                lens = {row_len[r] for r in idx}
+                cs_eff = max(1, min(chunk_size or len(idx), len(idx)))
+                skip = 0
+                if ckpt is not None:
+                    skip = ckpt.restore_call(call_key, idx, cs_eff, cols,
+                                             len(specs))
+                    if skip:
+                        done += skip
+                        if emit is not None:
+                            emit(done, total, time.perf_counter() - t0)
+                    if skip >= len(idx):
+                        continue
+                chunks = stream_batches(
+                    [workloads[rows[r][0]] for r in idx],
+                    [rows[r][1] for r in idx], cfg,
+                    device_mitigation=[rows[r][2].device for r in idx],
+                    rack_mitigation=[rows[r][2].rack for r in idx],
+                    specs=[sp for _, sp in specs],
+                    hw=hw, seeds=[rows[r][3] for r in idx],
+                    keys=None if keys is None else [keys[r] for r in idx],
+                    sample_chips=sample_chips,
+                    levels=[levels[rows[r][0]] for r in idx],
+                    pad_to=max(lens) if len(lens) > 1 else None,
+                    chunk_size=cs_eff,
+                    bands=True, keep_waveforms=keep_waveforms,
+                    dedup=True, shard_devices=shard_devices,
+                    plan=plan, skip_rows=skip)
+                for ch in chunks:
+                    with obs.span("repro.study.fill_chunk", rows=len(ch)):
+                        _fill_chunk(cols, waveforms, rows, row_len, idx, ch,
+                                    specs=specs, workloads=workloads,
+                                    dt=cfg.dt)
+                    if ckpt is not None and primary:
+                        ckpt.save_chunk(call_key, idx, ch.start, ch.stop,
+                                        cols, len(specs))
+                    done += len(ch)
                     if emit is not None:
                         emit(done, total, time.perf_counter() - t0)
-                if skip >= len(idx):
-                    continue
-            chunks = stream_batches(
-                [workloads[rows[r][0]] for r in idx],
-                [rows[r][1] for r in idx], cfg,
-                device_mitigation=[rows[r][2].device for r in idx],
-                rack_mitigation=[rows[r][2].rack for r in idx],
-                specs=[sp for _, sp in specs],
-                hw=hw, seeds=[rows[r][3] for r in idx],
-                keys=None if keys is None else [keys[r] for r in idx],
-                sample_chips=sample_chips,
-                levels=[levels[rows[r][0]] for r in idx],
-                pad_to=max(lens) if len(lens) > 1 else None,
-                chunk_size=cs_eff,
-                bands=True, keep_waveforms=keep_waveforms,
-                dedup=True, shard_devices=shard_devices,
-                plan=plan, skip_rows=skip)
-            for ch in chunks:
-                _fill_chunk(cols, waveforms, rows, row_len, idx, ch,
-                            specs=specs, workloads=workloads, dt=cfg.dt)
-                if ckpt is not None and primary:
-                    ckpt.save_chunk(call_key, idx, ch.start, ch.stop,
-                                    cols, len(specs))
-                done += len(ch)
-                if emit is not None:
-                    emit(done, total, time.perf_counter() - t0)
-    return StudyResult(columns=cols, waveforms=waveforms)
+        return StudyResult(columns=cols, waveforms=waveforms)
 
 
 def _fill_chunk(cols: Dict[str, np.ndarray], waveforms, rows, row_len,
