@@ -84,13 +84,18 @@ def stack_mitigations(mitigations: Sequence) -> object:
     All entries must be the same class with identical static metadata
     (hardware spec, telemetry config, windows); continuous parameters may
     differ per entry — that is the grid being swept.
+
+    Each leaf is one ``jnp.asarray`` of its rows: Python or NumPy values
+    are stacked in NumPy and go to the device in one transfer, where an
+    eager array per row and leaf cost ~0.7 ms a scalar on a TPU host.
+    ``jax.Array`` or traced rows are stacked on the device.  Either way
+    the leaf is a strong float32 with the rows leading.
     """
     mitigations = list(mitigations)
     if not mitigations:
         raise ValueError("empty mitigation list")
-    return jax.tree.map(
-        lambda *xs: jnp.stack([jnp.asarray(x, jnp.float32) for x in xs]),
-        *mitigations)
+    return jax.tree.map(lambda *xs: jnp.asarray(xs, jnp.float32),
+                        *mitigations)
 
 
 def _tile(values, B: int, what: str) -> list:

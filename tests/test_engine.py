@@ -5,6 +5,8 @@ The contract under test: for every mitigation, ``simulate_batch`` /
 stats, band reports and spec verdicts as looping the serial ``simulate`` /
 ``apply`` over the scenarios one at a time.
 """
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -92,6 +94,80 @@ def test_apply_batch_matches_serial(name):
                 np.testing.assert_allclose(
                     np.asarray(aux[k][i], np.float64), v,
                     rtol=1e-4, atol=1e-6, err_msg=f"{name}.{k}")
+
+
+# ---------------------------------------------------------------------------
+# config batching: leaves built on the host == the per-element device stack
+# ---------------------------------------------------------------------------
+
+STACK_B = 6
+
+
+def _stack_reference(mits):
+    """The stacking ``_normalize_mits`` did before leaves were built in
+    NumPy: one float32 device array per row and leaf, then a ``jnp.stack``
+    per leaf, and the on-mask from a Python list."""
+    mits = list(mits) * STACK_B if len(mits) == 1 else list(mits)
+    enabled = [m for m in mits if m is not None]
+    on = (None if len(enabled) == len(mits) else
+          jnp.asarray([0.0 if m is None else 1.0 for m in mits], jnp.float32))
+    rows = [enabled[0] if m is None else m for m in mits]
+    return jax.tree.map(
+        lambda *xs: jnp.stack([jnp.asarray(x, jnp.float32) for x in xs]),
+        *rows), on
+
+
+def _stack_row(kind, i):
+    """Row ``i`` of a class: values a float32 cannot hold exactly, a NumPy
+    scalar and a Python int among them."""
+    swing = 3.0e6 + i / 3
+    if kind == "gpu":
+        return _gpu(0.5 + i / 30, ramp_up_w_per_s=np.float64(2000 + i / 7))
+    if kind == "battery":
+        return core.RackBattery(capacity_j=(i + 1) * swing / 7,
+                                max_discharge_w=int(swing),
+                                max_charge_w=swing, target_tau_s=5.0 + i / 3)
+    if kind == "backstop":
+        return core.TelemetryBackstop(amp_threshold_w=1e5 * (i + 1) / 3,
+                                      alpha1=0.25 + i / 9)
+    return core.Stack([_stack_row("gpu", i), _stack_row("battery", i)])
+
+
+def _stack_rows(kind, rows):
+    if rows == "tiled":
+        return [_stack_row(kind, 1)]
+    mits = [_stack_row(kind, i) for i in range(STACK_B)]
+    if rows == "mixed":
+        mits[0] = mits[3] = None
+    return mits
+
+
+def _same_leaf(got, want):
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert got.weak_type == want.weak_type
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  np.asarray(want).view(np.uint32))
+
+
+@pytest.mark.parametrize("kind,rows,leaves", [
+    ("gpu", "all", "python"), ("battery", "all", "python"),
+    ("backstop", "all", "python"), ("stack", "all", "python"),
+    ("gpu", "mixed", "python"), ("stack", "mixed", "python"),
+    ("backstop", "tiled", "python"), ("battery", "mixed", "jax.Array")])
+def test_stacked_mitigations_match_per_element_stack(kind, rows, leaves):
+    mits = _stack_rows(kind, rows)
+    want, want_on = _stack_reference(mits)
+    if leaves == "jax.Array":
+        mits = [None if m is None else
+                jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), m)
+                for m in mits]
+    got, got_on = engine._normalize_mits(mits, STACK_B, "test")
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        _same_leaf(g, w)
+    assert (got_on is None) == (want_on is None)
+    if want_on is not None:
+        _same_leaf(got_on, want_on)
 
 
 # ---------------------------------------------------------------------------

@@ -123,6 +123,33 @@ def test_spans_are_on_the_profilers_host_line(study_runs):
     assert want <= names
 
 
+def test_stack_mits_spans_for_float_and_array_rows(study_runs, tmp_path):
+    """One ``repro.engine.stack_mits`` span a stage and chunk; rows given
+    as ``jax.Array`` leaves give the waveform Python floats give."""
+    stacks = [s.attrs for s in study_runs["snap"].spans
+              if s.name == "repro.engine.stack_mits"]
+    assert sorted(a["stage"] for a in stacks) == ["device", "device",
+                                                  "rack", "rack"]
+
+    cfg = core.WaveformConfig(dt=DT, steps=2)
+    tl = core.synthetic_timeline(period_s=1.0, comm_frac=0.3)
+    gpus = [core.GpuPowerSmoothing(mpf_frac=m, ramp_up_w_per_s=2000,
+                                   ramp_down_w_per_s=2000, stop_delay_s=1.0)
+            for m in (0.6, 0.8)]
+    on_device = [jax.tree.map(jax.numpy.asarray, g) for g in gpus]
+
+    def both():
+        return [core.simulate_batch(tl, 64, cfg, device_mitigation=rows,
+                                    spectra=False).dc_mitigated
+                for rows in (gpus, on_device)]
+
+    (host, device), snap = _traced(tmp_path, both)
+    np.testing.assert_array_equal(host, device)
+    stages = [s.attrs["stage"] for s in snap.spans
+              if s.name == "repro.engine.stack_mits"]
+    assert stages == ["device", "rack"] * 2
+
+
 def test_compiles_counted_in_the_span_that_ran_them(tmp_path):
     c = float(np.random.default_rng().random())   # a program never seen
     f = jax.jit(lambda x: x * c + 1.0)
