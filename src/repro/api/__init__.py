@@ -22,6 +22,7 @@ The engine functions behind this (``repro.core.engine``) remain available
 for direct use; the Study layer is the supported surface.
 """
 from repro.core.hardware import DEFAULT_HW, Hardware
+from repro.core.campus import Campus, Tenant
 from repro.core.phases import (IterationTimeline, Phase, from_dryrun_cell,
                                load_cell, synthetic_timeline)
 from repro.core.engine import (StreamChunk, design, design_gradient,
@@ -56,6 +57,7 @@ __all__ = [
     "watch_trace",
     # scenario ingredients
     "IterationTimeline", "Phase", "synthetic_timeline", "from_dryrun_cell",
+    "Campus", "Tenant",
     "load_cell", "WaveformConfig", "TelemetrySource",
     "Hardware", "DEFAULT_HW",
     # mitigations

@@ -6,6 +6,7 @@ smoothing, GB200-style device power floor, rack-level energy storage,
 telemetry backstop, combined design solver).
 """
 from repro.core.hardware import ChipSpec, DatacenterTopology, DEFAULT_HW, Hardware, ServerSpec
+from repro.core.campus import Campus, Tenant
 from repro.core.phases import (IterationTimeline, Phase, checkpoint_phase,
                                from_dryrun_cell, load_cell, synthetic_timeline)
 from repro.core.spec import (FrequencyDomainSpec, SpecReport, TimeDomainSpec,

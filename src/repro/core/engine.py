@@ -6,10 +6,11 @@ across a matrix of workloads, fleet sizes and (MPF, battery) configurations.
 StratoSim's ``simulate`` runs one scenario at a time; this module runs a
 *grid* of scenarios in a single compiled call:
 
-  ``simulate_batch``  vmaps (timeline levels x n_chips x mitigation config
-                      x jitter seed x PRNG key) through synthesis,
-                      aggregation, mitigation scans, swing/band metrics and
-                      utility-spec validation — no host round-trips inside.
+  ``simulate_batch``  vmaps (timeline or campus levels x n_chips x
+                      mitigation config x jitter seed x PRNG key)
+                      through synthesis, aggregation, mitigation scans,
+                      swing/band metrics and utility-spec validation —
+                      no host round-trips inside.
   ``sweep``           cartesian product over workloads / fleet sizes /
                       configs / seeds, bucketed by waveform length (each
                       bucket is one compiled call), returning flat records.
@@ -57,6 +58,7 @@ import numpy as np
 
 from repro import obs
 from repro.core.hardware import DEFAULT_HW, Hardware
+from repro.core.campus import n_samples, n_tenants, row_inputs
 from repro.core.optim import adam_init, adam_update, clip_by_global_norm
 from repro.core.phases import IterationTimeline
 from repro.parallel.collectives import gather_rows, host_allgather
@@ -69,7 +71,7 @@ from repro.core.spec import SpecReport, UtilitySpec, report_from_arrays
 from repro.core.spectrum import critical_band_report_jax
 from repro.core.stratosim import SimResult
 from repro.core.waveform import (WaveformConfig, aggregate_jax,
-                                 chip_waveform_jax, jitter_shifts,
+                                 chip_waveform_jax,
                                  phase_levels, swing_stats_jax)
 
 
@@ -172,16 +174,36 @@ def _mask_helpers(n: int, n_valid):
     return fill_edge, fill_mean, msum, mask
 
 
+def _each_tenant(fn, x, *rest):
+    """``fn(x, *rest)`` for each tenant of a row.  A campus row's inputs
+    lead with its tenant axis (``x`` [K, n]), which ``fn`` is mapped
+    over; a single job's row is one tenant (``x`` [n]) and ``fn`` runs on
+    it as it is."""
+    return fn(x, *rest) if x.ndim == 1 else jax.vmap(fn)(x, *rest)
+
+
+def _tenant_sum(w):
+    """The point of coupling's waveform: per-tenant waveforms [K, n]
+    summed, or a single job's [n] as it is."""
+    return w if w.ndim == 1 else jnp.sum(w, axis=0)
+
+
 def _synth_one(levels, shifts, n_chips, n_valid, cfg: WaveformConfig,
                hw: Hardware) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Mitigation-independent prefix: levels -> (chip, dc_raw).  Depends
-    only on (workload, fleet, seed) — the Study layer dedupes it across
-    the config axis (``simulate_grid``)."""
+    """Mitigation-independent prefix: levels -> (chip, dc_raw), the
+    chip waveform of each tenant and the sum of their jittered
+    aggregates.  Depends only on (workload or campus, fleet, seed) — the
+    Study layer dedupes it across the config axis."""
     fill_edge, _, _, _ = _mask_helpers(levels.shape[-1], n_valid)
-    chip = fill_edge(chip_waveform_jax(levels, cfg.dt, hw,
-                                       edp_spikes=cfg.edp_spikes,
-                                       include_host=cfg.include_host))
-    return chip, aggregate_jax(chip, n_chips, shifts, hw)
+
+    def one(lv, sh, nc):
+        chip = fill_edge(chip_waveform_jax(lv, cfg.dt, hw,
+                                           edp_spikes=cfg.edp_spikes,
+                                           include_host=cfg.include_host))
+        return chip, aggregate_jax(chip, nc, sh, hw)
+
+    chip, dc = _each_tenant(one, levels, shifts, n_chips)
+    return chip, _tenant_sum(dc)
 
 
 def _mitigate_one(chip, dc_raw, shifts, n_chips, dev, rack, dev_on, rack_on,
@@ -189,6 +211,11 @@ def _mitigate_one(chip, dc_raw, shifts, n_chips, dev, rack, dev_on, rack_on,
                   spec: Optional[UtilitySpec], spectra: bool,
                   chip_outputs: bool = True) -> Dict:
     """Per-config suffix of one scenario inside vmap.
+
+    The device stage runs on each tenant's chip waveform (every tenant
+    under the row's parameters, a campus tenant on a key of its own), and
+    each tenant's jittered aggregate adds to the point of coupling's
+    waveform; the rack stage and everything after it see only the sum.
 
     ``n_valid`` (traced scalar or None) activates pad-and-mask mode: the
     row's true waveform occupies the first ``n_valid`` samples of a padded
@@ -207,6 +234,9 @@ def _mitigate_one(chip, dc_raw, shifts, n_chips, dev, rack, dev_on, rack_on,
     if key is not None:
         k_dev = jax.random.fold_in(key, 0)
         k_rack = jax.random.fold_in(key, 1)
+        if chip.ndim == 2:
+            k_dev = jax.vmap(lambda t: jax.random.fold_in(k_dev, t))(
+                jnp.arange(chip.shape[0]))
 
     out: Dict = {"dc_raw": dc_raw}
     if chip_outputs:
@@ -214,14 +244,18 @@ def _mitigate_one(chip, dc_raw, shifts, n_chips, dev, rack, dev_on, rack_on,
     aux: Dict = {}
     dc = dc_raw
     if dev is not None:
-        chip_m, aux_d = apply_mitigation(dev, chip, cfg.dt, k_dev)
-        chip_m = fill_edge(chip_m)
-        if dev_on is not None:
-            chip_m = jnp.where(dev_on > 0, chip_m, chip)
-        aux["device"] = aux_d
+        def floor(c, sh, nc, k):
+            c_m, aux_d = apply_mitigation(dev, c, cfg.dt, k)
+            c_m = fill_edge(c_m)
+            if dev_on is not None:
+                c_m = jnp.where(dev_on > 0, c_m, c)
+            return c_m, aux_d, aggregate_jax(c_m, nc, sh, hw)
+
+        chip_m, aux["device"], dc = _each_tenant(floor, chip, shifts,
+                                                 n_chips, k_dev)
         if chip_outputs:
             out["chip_mitigated"] = chip_m
-        dc = aggregate_jax(chip_m, n_chips, shifts, hw)
+        dc = _tenant_sum(dc)
     if rack is not None:
         rack_in = fill_mean(dc)
         dc_r, aux_r = apply_mitigation(rack, rack_in, cfg.dt, k_rack)
@@ -420,8 +454,8 @@ class BatchResult:
             t=self.t[:n],
             dc_raw=self.dc_raw[i, :n], dc_mitigated=self.dc_mitigated[i, :n],
             chip_raw=(None if self.chip_raw is None
-                      else self.chip_raw[i, :n]),
-            chip_mitigated=(None if chip_m is None else chip_m[i, :n]),
+                      else self.chip_raw[i, ..., :n]),
+            chip_mitigated=(None if chip_m is None else chip_m[i, ..., :n]),
             energy_overhead=float(self.energy_overhead[i]),
             swing=row(self.swing), swing_mitigated=row(self.swing_mitigated),
             bands=(row(self.bands) if self.bands is not None else {}),
@@ -433,11 +467,11 @@ class BatchResult:
 
 def _prepare_rows(timelines, n_chips, seeds, device_mitigation,
                   rack_mitigation, levels, cfg: WaveformConfig, hw: Hardware):
-    """Broadcast every batched argument to a common row count B and expand
-    timelines to per-row ``phase_levels`` arrays (once per distinct
-    timeline — rows are usually a small set of workloads tiled across a
-    big config grid).  The shared prologue of ``simulate_batch`` and the
-    chunked ``stream_batches`` executor."""
+    """Broadcast every batched argument to a common row count B, and
+    count each row's samples (``lens``); a row's levels stay None unless
+    ``levels`` gives them, for ``_tenant_inputs`` to make.  The shared
+    prologue of ``simulate_batch`` and the chunked ``stream_batches``
+    executor."""
     tls = timelines if isinstance(timelines, (list, tuple)) else [timelines]
     chips = n_chips if isinstance(n_chips, (list, tuple)) else [n_chips]
     seed_list = seeds if isinstance(seeds, (list, tuple)) else [seeds]
@@ -453,14 +487,34 @@ def _prepare_rows(timelines, n_chips, seeds, device_mitigation,
     dev_list = _tile(dev_list, B, "device_mitigation")
     rack_list = _tile(rack_list, B, "rack_mitigation")
 
-    if levels is not None:
-        level_rows = _tile(list(levels), B, "levels")
-    else:
-        level_cache: Dict[int, np.ndarray] = {}
-        level_rows = [
-            level_cache.setdefault(id(tl), phase_levels(tl, cfg, hw))
-            for tl in tls]
-    return tls, chips, seed_list, dev_list, rack_list, level_rows, B
+    level_rows = ([None] * B if levels is None
+                  else _tile(list(levels), B, "levels"))
+    len_of: Dict[int, int] = {}
+    for tl, r in zip(tls, level_rows):
+        if r is None and id(tl) not in len_of:
+            len_of[id(tl)] = n_samples(tl, cfg, hw)
+    lens = [len_of[id(tl)] if r is None else r.shape[-1]
+            for tl, r in zip(tls, level_rows)]
+    return tls, chips, seed_list, dev_list, rack_list, level_rows, lens, B
+
+
+def _tenant_inputs(tls, chips, seeds, level_rows, cfg: WaveformConfig,
+                   hw: Hardware, sample_chips: int):
+    """Each row's per-tenant levels, chips and jitter shifts
+    (``campus.row_inputs``), made once for the rows of one workload,
+    fleet and seed, which share one levels array: the dedup keys on
+    it."""
+    if len({n_tenants(tl) for tl in tls}) > 1:
+        raise ValueError("rows of one call must have the same number of "
+                         "tenants")
+    cache: Dict[Tuple[int, int, int, float], Tuple] = {}
+    for tl, n, s, lv in zip(tls, chips, seeds, level_rows):
+        key = (id(tl), id(lv), s, n)
+        if key not in cache:
+            cache[key] = row_inputs(tl, s, n, cfg, hw, sample_chips, lv)
+    return [list(x) for x in zip(*(
+        cache[id(tl), id(lv), s, n]
+        for tl, n, s, lv in zip(tls, chips, seeds, level_rows)))]
 
 
 def simulate_batch(
@@ -495,6 +549,13 @@ def simulate_batch(
     call; frequency/spec analysis then runs per true length via
     ``analyze_batch`` (``spec`` must be None and ``spectra`` False).
 
+    A ``timelines`` entry may be a ``Campus`` (``core/campus.py``):
+    several jobs behind one grid connection, its ``n_chips`` the
+    campus's total.  Its row carries a tenant axis (levels [K, n], chips
+    [K], shifts [K, S]) through synthesis and the device stage, and the
+    rack stage and every metric see the tenants' sum; rows of one call
+    share a tenant count.  A campus of one tenant is a single-job row.
+
     ``levels`` optionally supplies per-row ``phase_levels`` arrays
     precomputed; ``plan`` (a ``ScenarioShardPlan``) partitions the
     scenario axis across its mesh — ``shard_devices=True`` is shorthand
@@ -506,10 +567,13 @@ def simulate_batch(
     row's physics actually depends on.
     """
     cfg = wave_cfg or WaveformConfig()
-    (tls, chips, seed_list, dev_list, rack_list, level_rows,
+    (tls, chips, seed_list, dev_list, rack_list, level_rows, _,
      B) = _prepare_rows(timelines, n_chips, seeds, device_mitigation,
                         rack_mitigation, levels, cfg, hw)
 
+    with obs.span("repro.engine.tenants"):
+        level_rows, tenant_chips, shift_rows = _tenant_inputs(
+            tls, chips, seed_list, level_rows, cfg, hw, sample_chips)
     with obs.span("repro.engine.inputs"):
         src_ids = [id(r) for r in level_rows]   # pre-padding row identity
         n_valid_arr = None
@@ -518,25 +582,25 @@ def simulate_batch(
                 raise ValueError(
                     "pad_to defers frequency/spec analysis to analyze_batch "
                     "on the sliced rows: call with spec=None, spectra=False")
-            lens = [len(r) for r in level_rows]
+            lens = [r.shape[-1] for r in level_rows]
             if max(lens) > pad_to:
                 raise ValueError(
                     f"pad_to={pad_to} < longest workload {max(lens)}")
             n_valid_arr = jnp.asarray(lens, jnp.float32)
-            level_rows = [np.pad(r, (0, pad_to - len(r)), mode="edge")
+            level_rows = [np.pad(r, [(0, 0)] * (r.ndim - 1)
+                                 + [(0, pad_to - r.shape[-1])], mode="edge")
                           for r in level_rows]
         else:
-            n0 = len(level_rows[0])
-            if any(len(r) != n0 for r in level_rows):
+            n0 = level_rows[0].shape[-1]
+            if any(r.shape[-1] != n0 for r in level_rows):
                 raise ValueError(
                     "all timelines in one simulate_batch call must expand to "
                     "the same sample count (got "
                     f"{sorted({len(r) for r in level_rows})}); use "
                     "sweep()/Study to bucket, or pad_to to fuse")
-        n = len(level_rows[0])
-        shifts = jnp.asarray(np.stack(
-            [jitter_shifts(cfg, s, sample_chips) for s in seed_list]))
-        chips_f = jnp.asarray(np.asarray(chips, np.float32))
+        n = level_rows[0].shape[-1]
+        shifts = jnp.asarray(np.stack(shift_rows))
+        chips_f = jnp.asarray(np.asarray(tenant_chips, np.float32))
         keys_arr = _normalize_keys(keys, B)
         # family/limits split: the spec's *structure* is the static jit
         # key, its numeric thresholds ride in as traced scalars — every
@@ -734,7 +798,7 @@ def stream_batches(
     """
     cfg = wave_cfg or WaveformConfig()
     with obs.span("repro.stream.prepare") as prep:
-        (tls, chips, seed_list, dev_list, rack_list, level_rows,
+        (tls, chips, seed_list, dev_list, rack_list, level_rows, lens,
          B) = _prepare_rows(timelines, n_chips, seeds, device_mitigation,
                             rack_mitigation, levels, cfg, hw)
         prep.attrs["rows"] = B
@@ -745,7 +809,6 @@ def stream_batches(
                     else (sp.family(), sp.limits()) for sp in spec_list]
         keys_arr = _normalize_keys(keys, B)
 
-        lens = [len(r) for r in level_rows]
         if pad_to is None and len(set(lens)) > 1:
             pad_to = max(lens)
         chunk_size = max(1, min(chunk_size, B))
@@ -874,7 +937,8 @@ def stream_batches(
             continue
         tail = chunk_size - (hi - lo) if n_chunks > 1 else 0
         with obs.span("repro.stream.dispatch", lo=lo, rows=hi - lo,
-                      tail_pad=tail):
+                      tail_pad=tail,
+                      tenant_rows=sum(map(n_tenants, tls[lo:hi]))):
             cur = dispatch(lo, hi, tail)
         if pending is not None:
             yield pulled(pending)

@@ -67,14 +67,14 @@ import jax
 import numpy as np
 
 from repro import obs
+from repro.core.campus import Campus, n_samples, n_tenants
 from repro.core.engine import StreamChunk, design, stream_batches
 from repro.core.hardware import DEFAULT_HW, Hardware
 from repro.core.phases import IterationTimeline
 from repro.core.smoothing.base import Mitigation
 from repro.core.spec import UtilitySpec
 from repro.core.spectrum import critical_band_report
-from repro.core.waveform import (WaveformConfig, aggregate, chip_waveform,
-                                 phase_levels)
+from repro.core.waveform import WaveformConfig, aggregate, chip_waveform
 from repro.core.stratosim import SimResult
 from repro.ckpt.resume import SweepCheckpoint
 from repro.parallel.sharding import ScenarioShardPlan
@@ -149,8 +149,8 @@ def _as_configs(configs) -> List[MitigationConfig]:
     return out
 
 
-def _as_workloads(workloads) -> Dict[str, IterationTimeline]:
-    if isinstance(workloads, IterationTimeline):
+def _as_workloads(workloads) -> Dict[str, Union[IterationTimeline, Campus]]:
+    if isinstance(workloads, (IterationTimeline, Campus)):
         return {"workload0": workloads}
     if isinstance(workloads, Mapping):
         return dict(workloads)
@@ -202,7 +202,7 @@ def _structure_groups(rows) -> List[List[int]]:
     return list(groups.values())
 
 
-def run_rows(workloads: Mapping[str, IterationTimeline],
+def run_rows(workloads: Mapping[str, Union[IterationTimeline, Campus]],
              rows: Sequence[Tuple[str, int, MitigationConfig, int]],
              specs: Sequence[Tuple[Optional[str], Optional[UtilitySpec]]],
              *, wave_cfg: Optional[WaveformConfig] = None,
@@ -229,7 +229,9 @@ def run_rows(workloads: Mapping[str, IterationTimeline],
     (each query's rows carrying the keys that query would draw alone, so
     coalescing is bit-identical to running the queries one at a time).
     ``levels`` optionally supplies precomputed ``phase_levels`` arrays per
-    workload name (the serve layer's memoized synthesis).
+    workload name (the serve layer's memoized synthesis).  A workload may
+    be a ``Campus``: its row's ``n_chips`` is the campus's total, and its
+    levels, which depend on the seed, are always made by the engine.
 
     Rows are grouped by mitigation *structure* (a GPU-floor grid and a
     Firefly grid cannot stack into one batched pytree; disabled rows join
@@ -273,11 +275,9 @@ def run_rows(workloads: Mapping[str, IterationTimeline],
         if levels is None:
             levels = {}
         needed = {w for w, _, _, _ in rows}
-        levels = dict(levels)
-        for w in needed:
-            if w not in levels:
-                levels[w] = phase_levels(workloads[w], cfg, hw)
-        row_len = [len(levels[w]) for w, _, _, _ in rows]
+        lengths = {w: n_samples(workloads[w], cfg, hw) if w not in levels
+                   else len(levels[w]) for w in needed}
+        row_len = [lengths[w] for w, _, _, _ in rows]
         mode = padding
         if mode == "auto":
             mode = "pad" if len(set(row_len)) > 1 else "bucket"
@@ -311,14 +311,21 @@ def run_rows(workloads: Mapping[str, IterationTimeline],
         total, done = len(rows), 0
         t0 = time.perf_counter()
         for gi, sg_rows in enumerate(_structure_groups(rows)):
-            if mode == "pad":
-                calls = [(f"g{gi}-pad", sg_rows)]
-            else:
+            # rows of one call stack: they share a number of tenants
+            by_k: Dict[int, List[int]] = {}
+            for r in sg_rows:
+                by_k.setdefault(n_tenants(workloads[rows[r][0]]), []).append(r)
+            calls = []
+            for k, k_rows in sorted(by_k.items()):
+                prefix = f"g{gi}" if k == 1 else f"g{gi}-t{k}"
+                if mode == "pad":
+                    calls.append((f"{prefix}-pad", k_rows))
+                    continue
                 by_len: Dict[int, List[int]] = {}
-                for r in sg_rows:
+                for r in k_rows:
                     by_len.setdefault(row_len[r], []).append(r)
-                calls = [(f"g{gi}-L{L}", idx)
-                         for L, idx in sorted(by_len.items())]
+                calls += [(f"{prefix}-L{L}", idx)
+                          for L, idx in sorted(by_len.items())]
             for call_key, idx in calls:
                 lens = {row_len[r] for r in idx}
                 cs_eff = max(1, min(chunk_size or len(idx), len(idx)))
@@ -341,7 +348,7 @@ def run_rows(workloads: Mapping[str, IterationTimeline],
                     hw=hw, seeds=[rows[r][3] for r in idx],
                     keys=None if keys is None else [keys[r] for r in idx],
                     sample_chips=sample_chips,
-                    levels=[levels[rows[r][0]] for r in idx],
+                    levels=[levels.get(rows[r][0]) for r in idx],
                     pad_to=max(lens) if len(lens) > 1 else None,
                     chunk_size=cs_eff,
                     bands=True, keep_waveforms=keep_waveforms,
@@ -423,8 +430,9 @@ class Study:
     """A declared scenario grid; ``run()`` compiles it to the engine.
 
     Axes (each a singleton or a collection):
-      workloads  name -> IterationTimeline (dict, sequence, or one timeline)
-      fleets     chip counts
+      workloads  name -> IterationTimeline or Campus (dict, sequence, or
+                 one of them)
+      fleets     chip counts (a campus's total)
       configs    name -> None | MitigationConfig | (device, rack) pair
       specs      None | UtilitySpec | dict name -> spec | sequence
       seeds      jitter seeds (numpy side: per-chip phase jitter draws)
@@ -503,7 +511,7 @@ class Study:
         return jax.random.fold_in(root, row)
 
     def describe(self) -> str:
-        lens = sorted({len(phase_levels(tl, self.wave_cfg, self.hw))
+        lens = sorted({n_samples(tl, self.wave_cfg, self.hw)
                        for tl in self.workloads.values()})
         return (f"Study: {len(self.workloads)} workloads x "
                 f"{len(self.fleets)} fleets x {len(self.configs)} configs x "
@@ -592,6 +600,9 @@ class Study:
         seed = self.seeds[0] if seed is None else int(seed)
         records: List[Dict] = []
         for wname, tl in self.workloads.items():
+            if isinstance(tl, Campus):
+                raise ValueError(f"optimize() designs for one job; "
+                                 f"{wname!r} is a Campus")
             chip = chip_waveform(tl, cfg, hw)
             for n_chips in self.fleets:
                 w = aggregate(chip, n_chips, cfg, hw, seed=seed,
