@@ -114,10 +114,12 @@ def _sim_inputs(B: int = 2, spec=None):
 
 def _build_simulate_step():
     """The engine's compiled scenario step (synthesis -> mitigation ->
-    metrics -> spec verdicts), B=2 scenarios, spec validation on."""
+    metrics -> spec verdicts), B=2 scenarios, spec validation on, with
+    the aggregation band ``simulate_batch`` would pick for the shifts."""
     import jax.numpy as jnp
     from repro.core import engine
     from repro.core.spec import example_specs
+    from repro.core.waveform import jitter_reach
 
     spec = example_specs(job_mw=1.0)["moderate"]
     si = _sim_inputs()
@@ -129,7 +131,8 @@ def _build_simulate_step():
                  jnp.full((B,), 256.0, jnp.float32), si["gpus"], si["bats"],
                  on, on, si["keys"], None, limits),
             dict(cfg=si["cfg"], hw=si["hw"], spec=spec.family(),
-                 spectra=False, plan=None))
+                 spectra=False, plan=None,
+                 reach=jitter_reach(si["shifts"], si["cfg"], si["n"])))
 
 
 def _build_design_gradient_step():

@@ -71,7 +71,7 @@ from repro.core.spec import SpecReport, UtilitySpec, report_from_arrays
 from repro.core.spectrum import critical_band_report_jax
 from repro.core.stratosim import SimResult
 from repro.core.waveform import (WaveformConfig, aggregate_jax,
-                                 chip_waveform_jax,
+                                 chip_waveform_jax, jitter_reach,
                                  phase_levels, swing_stats_jax)
 
 
@@ -189,18 +189,20 @@ def _tenant_sum(w):
 
 
 def _synth_one(levels, shifts, n_chips, n_valid, cfg: WaveformConfig,
-               hw: Hardware) -> Tuple[jnp.ndarray, jnp.ndarray]:
+               hw: Hardware, reach: int
+               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Mitigation-independent prefix: levels -> (chip, dc_raw), the
     chip waveform of each tenant and the sum of their jittered
     aggregates.  Depends only on (workload or campus, fleet, seed) — the
-    Study layer dedupes it across the config axis."""
+    Study layer dedupes it across the config axis.  ``reach`` is
+    ``aggregate_jax``'s band half-width (``jitter_reach``)."""
     fill_edge, _, _, _ = _mask_helpers(levels.shape[-1], n_valid)
 
     def one(lv, sh, nc):
         chip = fill_edge(chip_waveform_jax(lv, cfg.dt, hw,
                                            edp_spikes=cfg.edp_spikes,
                                            include_host=cfg.include_host))
-        return chip, aggregate_jax(chip, nc, sh, hw)
+        return chip, aggregate_jax(chip, nc, sh, hw, reach=reach)
 
     chip, dc = _each_tenant(one, levels, shifts, n_chips)
     return chip, _tenant_sum(dc)
@@ -209,7 +211,7 @@ def _synth_one(levels, shifts, n_chips, n_valid, cfg: WaveformConfig,
 def _mitigate_one(chip, dc_raw, shifts, n_chips, dev, rack, dev_on, rack_on,
                   key, n_valid, limits, cfg: WaveformConfig, hw: Hardware,
                   spec: Optional[UtilitySpec], spectra: bool,
-                  chip_outputs: bool = True) -> Dict:
+                  chip_outputs: bool, reach: int) -> Dict:
     """Per-config suffix of one scenario inside vmap.
 
     The device stage runs on each tenant's chip waveform (every tenant
@@ -221,7 +223,7 @@ def _mitigate_one(chip, dc_raw, shifts, n_chips, dev, rack, dev_on, rack_on,
     row's true waveform occupies the first ``n_valid`` samples of a padded
     array.  Masking keeps the valid region *exact* against an unpadded run:
     levels arrive edge-padded, mitigated chip waveforms are re-filled with
-    their boundary sample (so the jittered aggregation gather sees the same
+    their boundary sample (so the jittered aggregation sees the same
     clip-to-edge semantics as an unpadded call), mean-sensitive rack
     stages see the pad region filled with the valid-region mean, and every
     scalar metric is a masked reduction.  Frequency metrics need the true
@@ -249,7 +251,7 @@ def _mitigate_one(chip, dc_raw, shifts, n_chips, dev, rack, dev_on, rack_on,
             c_m = fill_edge(c_m)
             if dev_on is not None:
                 c_m = jnp.where(dev_on > 0, c_m, c)
-            return c_m, aux_d, aggregate_jax(c_m, nc, sh, hw)
+            return c_m, aux_d, aggregate_jax(c_m, nc, sh, hw, reach=reach)
 
         chip_m, aux["device"], dc = _each_tenant(floor, chip, shifts,
                                                  n_chips, k_dev)
@@ -300,11 +302,13 @@ def _swing_stats_masked(w, mask, n_valid) -> Dict[str, jnp.ndarray]:
 
 
 def _simulate_one(levels, shifts, n_chips, dev, rack, dev_on, rack_on, key,
-                  n_valid, limits, cfg, hw, spec, spectra) -> Dict:
-    chip, dc_raw = _synth_one(levels, shifts, n_chips, n_valid, cfg, hw)
+                  n_valid, limits, cfg, hw, spec, spectra,
+                  reach: int) -> Dict:
+    chip, dc_raw = _synth_one(levels, shifts, n_chips, n_valid, cfg, hw,
+                              reach)
     return _mitigate_one(chip, dc_raw, shifts, n_chips, dev, rack, dev_on,
                          rack_on, key, n_valid, limits, cfg, hw, spec,
-                         spectra)
+                         spectra, True, reach)
 
 
 def _over_rows(rows, plan: Optional[ScenarioShardPlan], shared, row_args):
@@ -331,17 +335,22 @@ def _over_rows(rows, plan: Optional[ScenarioShardPlan], shared, row_args):
 # waveform outputs, so a streaming chunk holds one buffer fewer in flight.
 # ``spec`` is the spec's *family* (shape structure only — static) and
 # ``limits`` its traced thresholds, so same-family specs share the
-# executable (see UtilitySpec.family()).
+# executable (see UtilitySpec.family()).  ``reach`` (static) is the
+# jittered aggregation's band half-width, ``jitter_reach`` of the call's
+# shifts.
 @functools.partial(jax.jit, donate_argnums=(0,),
-                   static_argnames=("cfg", "hw", "spec", "spectra", "plan"))
+                   static_argnames=("cfg", "hw", "spec", "spectra", "plan",
+                                    "reach"))
 def _simulate_vmapped(levels, shifts, n_chips, dev, rack, dev_on, rack_on,
                       keys, n_valid, limits, *, cfg: WaveformConfig,
                       hw: Hardware, spec: Optional[UtilitySpec],
-                      spectra: bool, plan: Optional[ScenarioShardPlan]):
+                      spectra: bool, plan: Optional[ScenarioShardPlan],
+                      reach: int):
     def rows(limits, *row_args):
         return jax.vmap(
             lambda L, S, N, D, R, Do, Ro, K, V: _simulate_one(
-                L, S, N, D, R, Do, Ro, K, V, limits, cfg, hw, spec, spectra)
+                L, S, N, D, R, Do, Ro, K, V, limits, cfg, hw, spec, spectra,
+                reach)
         )(*row_args)
     return _over_rows(rows, plan, (limits,),
                       (levels, shifts, n_chips, dev, rack, dev_on, rack_on,
@@ -349,21 +358,22 @@ def _simulate_vmapped(levels, shifts, n_chips, dev, rack, dev_on, rack_on,
 
 
 @functools.partial(jax.jit, donate_argnums=(0,),
-                   static_argnames=("cfg", "hw"))
+                   static_argnames=("cfg", "hw", "reach"))
 def _synth_vmapped(levels, shifts, n_chips, n_valid, *, cfg: WaveformConfig,
-                   hw: Hardware):
+                   hw: Hardware, reach: int):
     return jax.vmap(
-        lambda L, S, N, V: _synth_one(L, S, N, V, cfg, hw)
+        lambda L, S, N, V: _synth_one(L, S, N, V, cfg, hw, reach)
     )(levels, shifts, n_chips, n_valid)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "hw", "spec", "spectra",
-                                             "chip_outputs", "plan"))
+                                             "chip_outputs", "plan", "reach"))
 def _mitigate_vmapped(chip_u, dcraw_u, u_idx, shifts, n_chips, dev, rack,
                       dev_on, rack_on, keys, n_valid, limits, *,
                       cfg: WaveformConfig, hw: Hardware,
                       spec: Optional[UtilitySpec], spectra: bool,
-                      chip_outputs: bool, plan: Optional[ScenarioShardPlan]):
+                      chip_outputs: bool, plan: Optional[ScenarioShardPlan],
+                      reach: int):
     """Per-scenario suffix over rows that *share* synthesized prefixes:
     ``chip_u``/``dcraw_u`` hold one entry per unique (workload, fleet,
     seed) and ``u_idx`` maps each scenario row to its prefix."""
@@ -371,7 +381,7 @@ def _mitigate_vmapped(chip_u, dcraw_u, u_idx, shifts, n_chips, dev, rack,
         return jax.vmap(
             lambda U, S, N, D, R, Do, Ro, K, V: _mitigate_one(
                 chip_u[U], dcraw_u[U], S, N, D, R, Do, Ro, K, V, limits,
-                cfg, hw, spec, spectra, chip_outputs)
+                cfg, hw, spec, spectra, chip_outputs, reach)
         )(*row_args)
     return _over_rows(rows, plan, (chip_u, dcraw_u, limits),
                       (u_idx, shifts, n_chips, dev, rack, dev_on, rack_on,
@@ -599,7 +609,10 @@ def simulate_batch(
                     f"{sorted({len(r) for r in level_rows})}); use "
                     "sweep()/Study to bucket, or pad_to to fuse")
         n = level_rows[0].shape[-1]
-        shifts = jnp.asarray(np.stack(shift_rows))
+        shift_rows = np.stack(shift_rows)
+        # one band for every row of the call, the dedup prefix included
+        reach = jitter_reach(shift_rows, cfg, n)
+        shifts = jnp.asarray(shift_rows)
         chips_f = jnp.asarray(np.asarray(tenant_chips, np.float32))
         keys_arr = _normalize_keys(keys, B)
         # family/limits split: the spec's *structure* is the static jit
@@ -632,7 +645,7 @@ def simulate_batch(
         rack, rack_on = _normalize_mits(rack_list, B, "rack_mitigation")
 
     out_B = B
-    with obs.span("repro.engine.enqueue"):
+    with obs.span("repro.engine.enqueue", aggregate="band", reach=reach):
         if dedup:
             if shard is not None and shard.n_processes > 1:
                 # global arrays only compose with global arrays in one
@@ -640,7 +653,8 @@ def simulate_batch(
                 # scenario mesh too (pad rows are duplicates no ``u_idx``
                 # ever references)
                 synth_in, _ = shard.shard_batch(synth_in, len(u_rows))
-            chip_u, dcraw_u = _synth_vmapped(*synth_in, cfg=cfg, hw=hw)
+            chip_u, dcraw_u = _synth_vmapped(*synth_in, cfg=cfg, hw=hw,
+                                             reach=reach)
             row_args = (u_idx, shifts, chips_f, dev, rack, dev_on, rack_on,
                         keys_arr, n_valid_arr)
             if shard is not None:
@@ -648,7 +662,8 @@ def simulate_batch(
             res = _mitigate_vmapped(chip_u, dcraw_u, *row_args, limits,
                                     cfg=cfg, hw=hw, spec=family,
                                     spectra=spectra,
-                                    chip_outputs=chip_outputs, plan=shard)
+                                    chip_outputs=chip_outputs, plan=shard,
+                                    reach=reach)
         else:
             args = (jnp.asarray(np.stack(level_rows), jnp.float32), shifts,
                     chips_f, dev, rack, dev_on, rack_on, keys_arr,
@@ -656,7 +671,8 @@ def simulate_batch(
             if shard is not None:
                 args, out_B = shard.shard_batch(args, B)
             res = _simulate_vmapped(*args, limits, cfg=cfg, hw=hw,
-                                    spec=family, spectra=spectra, plan=shard)
+                                    spec=family, spectra=spectra, plan=shard,
+                                    reach=reach)
     if host_arrays:
         # single-process this is the plain np.asarray(+slice) host pull;
         # multi-process it is one replicate-all collective first

@@ -146,17 +146,52 @@ def aggregate(chip_wave: np.ndarray, n_chips: int, cfg: WaveformConfig,
     return total * (1.0 + hw.topo.distribution_loss)
 
 
+def jitter_reach(shift_rows, cfg: WaveformConfig, n: int) -> int:
+    """The band half-width ``reach`` for ``aggregate_jax`` over every row
+    of one call: 0 when no shift is nonzero (the jitter-off [1] row),
+    else the smallest power of two that is at least the largest |shift|,
+    at least 8 and at least 8 sigma (``cfg.jitter_s / cfg.dt``), capped
+    at n - 1.  The 8-sigma floor keeps one bucket for every seed block
+    of a deployment, so a new block compiles nothing."""
+    # a shift beyond n - 1 lands on the same edge sample as n - 1
+    m = min(int(np.abs(np.asarray(shift_rows)).max(initial=0)), n - 1)
+    if m == 0:
+        return 0
+    reach = 8
+    while reach < max(m, 8.0 * cfg.jitter_s / cfg.dt):
+        reach <<= 1
+    return min(reach, n - 1)
+
+
 def aggregate_jax(chip_wave: jnp.ndarray, n_chips, shifts,
-                  hw: Hardware = DEFAULT_HW) -> jnp.ndarray:
-    """jnp mirror of ``aggregate``: one gather against a [S, n] shift-index
-    matrix replaces the per-sample-chip roll loop; edge-padded like the
-    numpy path.  ``shifts`` comes from ``jitter_shifts`` ([1] zero when
-    jitter is off); ``n_chips`` may be a traced scalar."""
+                  hw: Hardware = DEFAULT_HW, *, reach: int) -> jnp.ndarray:
+    """jnp mirror of ``aggregate``: the mean of the S edge-clamped
+    shifted replicas, scaled to ``n_chips``.  ``shifts`` comes from
+    ``jitter_shifts`` ([1] zero when jitter is off); ``n_chips`` may be a
+    traced scalar.
+
+    The mean is a banded sum over the row's shift histogram: edge-pad
+    the trace by the static ``reach`` R (``jitter_reach``), and add the
+    2R + 1 slices of the padded trace, each weighted by how many shifts
+    fall on its value, in plain float32 multiply-adds.  Empty bins add
+    exact zeros, so the result does not depend on R.  R must be at least
+    every |shift| (those beyond n - 1 count as n - 1).  The loop over
+    slices is unrolled 17 at a time, the band of the 8-sample floor, so
+    that band is one fused pass and a wide one still compiles small."""
     n = chip_wave.shape[-1]
-    shifts = jnp.asarray(shifts)
-    idx = jnp.clip(jnp.arange(n)[None, :] - shifts[:, None], 0, n - 1)
-    total = chip_wave[idx].mean(axis=0) * n_chips
-    return total * (1.0 + hw.topo.distribution_loss)
+    sh = jnp.clip(jnp.asarray(shifts), -(n - 1), n - 1)
+    pad = jnp.pad(jnp.asarray(chip_wave), reach, mode="edge")
+
+    def add(j, acc):
+        # slice j of the padded trace is the replica shifted by R - j
+        count = jnp.sum(sh == reach - j, dtype=pad.dtype)
+        return acc + count * jax.lax.dynamic_slice_in_dim(pad, j, n)
+
+    acc = jax.lax.fori_loop(0, 2 * reach + 1, add,
+                            jnp.zeros(n, pad.dtype),
+                            unroll=min(2 * reach + 1, 17))
+    mean = acc / sh.shape[-1]
+    return mean * n_chips * (1.0 + hw.topo.distribution_loss)
 
 
 def job_waveform(tl: IterationTimeline, n_chips: int,

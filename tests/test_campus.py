@@ -50,7 +50,7 @@ CONFIGS = {"none": None, "floor": (_gpu(), None),
            "floor+battery": (_gpu(), _battery())}
 
 
-def _ref_row(campus, seed, config, tenants=None):
+def _ref_row(campus, seed, config, tenants=None, sample_chips=SAMPLE_CHIPS):
     """The float64 reference's (raw, floor-mitigated, mitigated) of one
     campus row, from plain numbers."""
     hw = core.DEFAULT_HW
@@ -73,7 +73,7 @@ def _ref_row(campus, seed, config, tenants=None):
             "edp_factor": hw.chip.edp_factor,
             "edp_window_s": hw.chip.edp_window_s,
             "loss": hw.topo.distribution_loss},
-        jitter_s=0.002, sample_chips=SAMPLE_CHIPS, floor=floor, bat=battery)
+        jitter_s=0.002, sample_chips=sample_chips, floor=floor, bat=battery)
 
 
 def _ref_spec(spec):
@@ -133,9 +133,9 @@ def _study(workloads, **kw):
     kw.setdefault("fleets", [N_CHIPS])
     kw.setdefault("configs", CONFIGS)
     kw.setdefault("seeds", SEEDS)
+    kw.setdefault("sample_chips", SAMPLE_CHIPS)
     return core.Study(workloads, specs={"moderate": _spec()},
-                      wave_cfg=_cfg(), sample_chips=SAMPLE_CHIPS,
-                      padding="pad", key=None, **kw)
+                      wave_cfg=_cfg(), padding="pad", key=None, **kw)
 
 
 @pytest.mark.parametrize("stream", [None, 4], ids=["oneshot", "stream4"])
@@ -171,22 +171,33 @@ def test_tolerances_fail_bfloat16_and_a_dropped_tenant():
         assert _mismatches(ref.record(raw2, mit2, DT, spec), want, spec)
 
 
-def test_one_tenant_campus_is_the_single_job_row():
-    """A campus of one tenant is a single-job row: bit-identical records
-    to the job run alone on the campus's levels, chips and seed."""
+def _one_tenant_campus_is_the_single_job_row(sample_chips):
     name, p, c, m, _ = TENANTS[1]
     campus = _campus([(name, p, c, m, 1.0)])
     tl = campus.tenants[0].timeline
     for seed in SEEDS:
-        one = _study({"campus": campus}, seeds=[seed]).run(stream=2)
+        one = _study({"campus": campus}, seeds=[seed],
+                     sample_chips=sample_chips).run(stream=2)
         job = core.study.run_rows(
             {"campus": tl},
             [(w, n, cf, s) for w, n, cf, s in _study(
                 {"campus": tl}, seeds=[seed]).rows()],
             [("moderate", _spec())], wave_cfg=_cfg(), padding="pad",
-            stream=2, sample_chips=SAMPLE_CHIPS,
+            stream=2, sample_chips=sample_chips,
             levels={"campus": campus.levels(seed, _cfg())[0]})
         assert one.records == job.records
+
+
+def test_one_tenant_campus_is_the_single_job_row():
+    """A campus of one tenant is a single-job row: bit-identical records
+    to the job run alone on the campus's levels, chips and seed."""
+    _one_tenant_campus_is_the_single_job_row(SAMPLE_CHIPS)
+
+
+def test_one_tenant_campus_is_the_single_job_row_at_64_sampled_chips():
+    """The same at the campus cell's 64 sampled chips, where the band of
+    the jittered aggregation (half-width 8) is narrower than the sample."""
+    _one_tenant_campus_is_the_single_job_row(64)
 
 
 def test_campus_is_the_sum_of_its_tenants_alone():
@@ -294,8 +305,14 @@ def test_campus_chunks_record_tenant_spans(tmp_path):
         ["repro.stream.dispatch"] * len(SEEDS)
 
 
-@pytest.mark.parametrize("dedup", [False, True])
-def test_simulate_batch_campus_rows_match_float64_reference(dedup):
+@pytest.mark.parametrize("dedup,sample_chips", [
+    pytest.param(False, SAMPLE_CHIPS, id="False"),
+    pytest.param(True, SAMPLE_CHIPS, id="True"),
+    # the campus cell's sample, with a band narrower than it
+    pytest.param(False, 64, id="False-64"),
+    pytest.param(True, 64, id="True-64")])
+def test_simulate_batch_campus_rows_match_float64_reference(dedup,
+                                                            sample_chips):
     """The engine's one-call path (``_simulate_vmapped``) and its
     deduplicated split take campus rows as the Study does."""
     campus, spec = _campus(), _spec()
@@ -304,11 +321,11 @@ def test_simulate_batch_campus_rows_match_float64_reference(dedup):
         campus, N_CHIPS, _cfg(),
         device_mitigation=[(CONFIGS[c] or (None, None))[0] for c, _ in rows],
         rack_mitigation=[(CONFIGS[c] or (None, None))[1] for c, _ in rows],
-        spec=spec, seeds=[s for _, s in rows], sample_chips=SAMPLE_CHIPS,
+        spec=spec, seeds=[s for _, s in rows], sample_chips=sample_chips,
         dedup=dedup)
     assert res.chip_raw.shape == (len(rows), 3, campus.n_samples(_cfg()))
     for i, (c, s) in enumerate(rows):
-        raw, _, mit = _ref_row(campus, s, c)
+        raw, _, mit = _ref_row(campus, s, c, sample_chips=sample_chips)
         want = ref.record(raw, mit, DT, _ref_spec(spec))
         report = res.report(i)
         got = {"mean_mw": res.swing["mean_w"][i] / 1e6,

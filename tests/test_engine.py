@@ -5,6 +5,8 @@ The contract under test: for every mitigation, ``simulate_batch`` /
 stats, band reports and spec verdicts as looping the serial ``simulate`` /
 ``apply`` over the scenarios one at a time.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -415,11 +417,109 @@ def test_aggregate_jitter_does_not_wrap_tail_to_head():
 
 
 def test_aggregate_jax_matches_numpy():
-    from repro.core.waveform import aggregate_jax, jitter_shifts
+    from repro.core.waveform import aggregate_jax, jitter_reach, jitter_shifts
     cfg = core.WaveformConfig(dt=0.001, steps=3, jitter_s=0.005)
     chip = core.chip_waveform(_timeline(), cfg)
     shifts = jitter_shifts(cfg, seed=7)
     ref = core.aggregate(chip, N_CHIPS, cfg, seed=7)
-    out = np.asarray(aggregate_jax(np.asarray(chip, np.float32),
-                                   float(N_CHIPS), shifts))
+    out = np.asarray(aggregate_jax(
+        np.asarray(chip, np.float32), float(N_CHIPS), shifts,
+        reach=jitter_reach(shifts, cfg, len(chip))))
     np.testing.assert_allclose(out, ref, rtol=1e-5)
+
+
+def _band_case(case):
+    """(chip, shifts, cfg) of one ``aggregate_jax`` case: chip [n] or, for
+    a campus row, [K, n] with shifts [K, S]."""
+    from repro.core.waveform import jitter_shifts
+    cfg = _cfg(jitter_s=(5 if case.startswith("sigma5") else 1) * DT)
+    chip = core.chip_waveform(_timeline(), cfg)
+    shifts = jitter_shifts(cfg, seed=3)
+    if case == "sigma5":
+        shifts = jitter_shifts(cfg, seed=4, sample_chips=256)
+    elif case == "jitter_off":
+        cfg = _cfg()
+        shifts = jitter_shifts(cfg, seed=3)
+    elif case == "edge_of_band":
+        shifts[:2] = (8, -8)
+    elif case == "beyond_n":
+        chip = chip[990:1010]
+        shifts[:2] = (97, -300)
+    elif case == "pad_and_mask":
+        chip = chip.copy()
+        chip[-500:] = chip[-501]
+    elif case == "campus":
+        chip = np.stack([np.roll(chip, 137 * k) for k in range(3)])
+        shifts = np.stack([jitter_shifts(cfg, seed=k) for k in range(3)])
+    return chip, shifts, cfg
+
+
+def _gather_aggregate(chip, n_chips, shifts):
+    """The mean of the S edge-clamped shifted replicas as one gather
+    against a [S, n] shift-index matrix, in float32: the banded sum's
+    term-by-term counterpart."""
+    n = chip.shape[-1]
+    idx = jnp.clip(jnp.arange(n)[None, :] - shifts[:, None], 0, n - 1)
+    return (chip[idx].mean(axis=0) * n_chips
+            * (1.0 + DEFAULT_HW.topo.distribution_loss))
+
+
+@pytest.mark.parametrize("case", [
+    "sigma1", "sigma5", "jitter_off", "edge_of_band", "beyond_n",
+    "pad_and_mask", "campus", "sigma5_wide"])
+def test_aggregate_band_matches_gather(case, monkeypatch):
+    """The banded sum over each row's shift histogram against a gather
+    of the S shifted replicas and against the float64 NumPy
+    ``aggregate``, also where the band is wider than S."""
+    from repro.core import waveform
+    chip, shifts, cfg = _band_case(case)
+    n = chip.shape[-1]
+    reach = waveform.jitter_reach(shifts, cfg, n)
+    assert (2 * reach + 1 > shifts.shape[-1]) == (case == "sigma5_wide")
+
+    def agg(fn):
+        f = lambda c, s: fn(c, float(N_CHIPS), s)
+        return np.asarray(jax.vmap(f)(chip.astype(np.float32), shifts)
+                          if chip.ndim == 2 else
+                          f(chip.astype(np.float32), shifts))
+
+    out = agg(functools.partial(waveform.aggregate_jax, reach=reach))
+    np.testing.assert_allclose(out, agg(_gather_aggregate), rtol=1e-6)
+    refs = []
+    for c, s in zip(chip.reshape(-1, n), shifts.reshape(-1, shifts.shape[-1])):
+        monkeypatch.setattr(waveform, "jitter_shifts", lambda *a, **k: s)
+        refs.append(waveform.aggregate(c, N_CHIPS, cfg))
+    np.testing.assert_allclose(out.reshape(-1, n), np.stack(refs), rtol=1e-5)
+    if case == "pad_and_mask":
+        # the valid region of an edge-filled row is the unpadded row's
+        short = waveform.aggregate_jax(chip[:-500].astype(np.float32),
+                                       float(N_CHIPS), shifts, reach=reach)
+        np.testing.assert_array_equal(out[:-500], np.asarray(short))
+
+
+def test_aggregate_band_does_not_depend_on_reach():
+    """Empty bins add exact zeros: a wider band than the shifts need
+    gives the same bits."""
+    from repro.core import waveform
+    chip, shifts, cfg = _band_case("sigma1")
+    chip = chip.astype(np.float32)
+    outs = [np.asarray(waveform.aggregate_jax(chip, float(N_CHIPS), shifts,
+                                              reach=r)) for r in (8, 16, 40)]
+    np.testing.assert_array_equal(outs[0], outs[1])
+    np.testing.assert_array_equal(outs[0], outs[2])
+
+
+@pytest.mark.parametrize("shifts,sigma,n,want", [
+    ([0], 0.0, 1000, 0),            # jitter off: one slice
+    ([3, -2, 0], 1.0, 1000, 8),     # the sweep cells' sigma of one sample
+    ([9, -1], 1.0, 1000, 16),       # a shift past the floor
+    ([-16, 2], 1.0, 1000, 16),
+    ([1, -1], 1.5, 1000, 16),       # the 8-sigma floor, 12
+    ([1, -1], 3.0, 1000, 32),
+    ([3, 0], 1.0, 6, 5),            # capped at n - 1
+    ([40, 0], 1.0, 6, 5),           # a shift beyond n
+])
+def test_jitter_reach_buckets(shifts, sigma, n, want):
+    from repro.core.waveform import jitter_reach
+    cfg = _cfg(jitter_s=sigma * DT)
+    assert jitter_reach(np.asarray([shifts], np.int32), cfg, n) == want

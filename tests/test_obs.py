@@ -20,7 +20,7 @@ CHUNK_SPANS = ("repro.stream.dispatch", "repro.stream.materialize",
                "repro.study.fill_chunk")
 
 
-def _study():
+def _study(seeds=(0, 1)):
     """Four rows, so ``stream=2`` runs two chunks."""
     cfg = core.WaveformConfig(dt=DT, steps=3, jitter_s=0.002)
     tl = core.synthetic_timeline(period_s=1.0, comm_frac=0.3)
@@ -30,7 +30,8 @@ def _study():
     dc = core.aggregate(core.chip_waveform(tl, cfg), 256, cfg)
     spec = example_specs(job_mw=dc.mean() / 1e6)["moderate"]
     return core.Study({"a": tl}, fleets=[256], configs=gpu,
-                      specs={"moderate": spec}, seeds=[0, 1], wave_cfg=cfg)
+                      specs={"moderate": spec}, seeds=list(seeds),
+                      wave_cfg=cfg)
 
 
 def _traced(logdir, fn):
@@ -114,6 +115,24 @@ def test_two_chunk_study_span_tree(study_runs):
     lo = sorted(s.attrs["lo"] for s in spans
                 if s.name == "repro.stream.dispatch")
     assert lo == [0, 2]
+
+
+def test_enqueue_span_names_the_aggregation(study_runs):
+    """2 ms jitter at 2 ms samples over 64 sampled chips, as the sweep
+    cells have it: every chunk sums a band of half-width 8."""
+    attrs = [s.attrs for s in study_runs["snap"].spans
+             if s.name == "repro.engine.enqueue"]
+    assert attrs == [{"aggregate": "band", "reach": 8}] * 2
+
+
+def test_new_seed_block_compiles_nothing(study_runs):
+    """The band's bucket holds for a new block of seeds at a sigma of one
+    sample, so the next Study reuses every executable."""
+    from repro.core import engine
+    fns = (engine._synth_vmapped, engine._mitigate_vmapped)
+    before = [f._cache_size() for f in fns]
+    _study(seeds=(1_000_000, 1_000_001)).run(stream=2)
+    assert [f._cache_size() for f in fns] == before
 
 
 def test_spans_are_on_the_profilers_host_line(study_runs):

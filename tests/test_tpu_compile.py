@@ -24,7 +24,7 @@ from repro.core.hardware import DEFAULT_HW
 from repro.core.smoothing import backstop
 from repro.core.smoothing.backstop import TelemetryBackstop
 from repro.core.spec import example_specs
-from repro.core.waveform import WaveformConfig, jitter_shifts
+from repro.core.waveform import WaveformConfig, jitter_reach, jitter_shifts
 from repro.kernels.goertzel.goertzel import (sliding_goertzel_v2_pallas,
                                              sliding_monitor_pallas)
 from repro.parallel.sharding import ScenarioShardPlan
@@ -84,7 +84,8 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, win, S, block_s):
 def _backstop_rows(B, n, cfg, sharding, replicated):
     """ShapeDtypeStructs for ``engine._mitigate_vmapped`` over ``B``
     scenario rows of ``n`` samples that all share one synthesized prefix,
-    with a ``TelemetryBackstop`` as the rack mitigation."""
+    with a ``TelemetryBackstop`` as the rack mitigation, the spec's family
+    and the aggregation band ``simulate_batch`` would pass."""
     def sds(a, sh):
         a = np.asarray(a)
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
@@ -99,7 +100,8 @@ def _backstop_rows(B, n, cfg, sharding, replicated):
             jax.tree.map(lambda a: sds(a, sharding), rack),
             None, None, None, None)
     limits = jax.tree.map(lambda a: sds(a, replicated), spec.limits())
-    return shared + rows + (limits,), spec.family()
+    return (shared + rows + (limits,), spec.family(),
+            jitter_reach(shifts, cfg, n))
 
 
 @pytest.mark.parametrize("chips", [1, 4])
@@ -114,13 +116,35 @@ def test_vmapped_backstop_compiles_for_v5e(topo, monkeypatch, chips):
     cfg = WaveformConfig(dt=0.001, steps=1, jitter_s=0.001)
     devices = topo.devices[:chips]
     plan = ScenarioShardPlan(Mesh(np.asarray(devices), ("scenario",)))
-    args, family = _backstop_rows(
+    args, family, reach = _backstop_rows(
         64, 18000, cfg, NamedSharding(plan.mesh, PartitionSpec("scenario")),
         NamedSharding(plan.mesh, PartitionSpec()))
     fn = jax.jit(engine._mitigate_vmapped.__wrapped__,
                  static_argnames=("cfg", "hw", "spec", "spectra",
-                                  "chip_outputs", "plan"))
+                                  "chip_outputs", "plan", "reach"))
     compiled = fn.lower(*args, cfg=cfg, hw=DEFAULT_HW, spec=family,
                         spectra=False, chip_outputs=False,
-                        plan=plan).compile()
+                        plan=plan, reach=reach).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_jittered_aggregation_compiles_without_an_index_matrix(one_chip):
+    """Waveform synthesis for 64 rows of 17,988 samples and 64 sampled
+    chips at a jitter of one sample, as the sweep cells run it: the
+    banded sum needs a few row-sized buffers, where a gather of the 64
+    shifted replicas would hold a [64, 64, n] index matrix (295 MB)."""
+    B, n, S = 64, 17988, 64
+    cfg = WaveformConfig(dt=0.002, steps=1, jitter_s=0.002)
+    shifts = np.stack([jitter_shifts(cfg, s, S) for s in range(B)])
+    reach = jitter_reach(shifts, cfg, n)
+    assert reach == 8
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    fn = jax.jit(engine._synth_vmapped.__wrapped__,
+                 static_argnames=("cfg", "hw", "reach"))
+    compiled = fn.lower(sds((B, n), jnp.float32), sds((B, S), jnp.int32),
+                        sds((B,), jnp.float32), None, cfg=cfg, hw=DEFAULT_HW,
+                        reach=reach).compile()
+    assert " gather(" not in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 16 * B * n * 4, temp
